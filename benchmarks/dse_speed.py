@@ -1,9 +1,9 @@
 """Abstract claim — "Canal enables fast design space exploration": IR
 generation + hardware lowering speed vs array size, plus the batched DSE
 engine: B fabric configurations emulated as one ``run_batch`` scan vs the
-serial per-config baseline, the fused engine (whole fixpoint + in-kernel
-PE eval per cycle) vs the sweep-at-a-time PR-1 path, batch-axis sharding
-across devices (in-process, plus a forced multi-device probe), and the
+serial per-config baseline, the fused engine (whole fixpoint + PE eval
+per cycle) vs the sweep-at-a-time PR-1 path, batch-axis sharding across
+the devices of this process, and the
 spec-addressed persistent result store: the same track sweep cold
 (computing + persisting) vs warm (served from the store, zero PnR) —
 appended to the repo-root ``BENCH_dse.json`` trajectory."""
@@ -17,7 +17,6 @@ import jax
 
 from repro.core.dse import (batched_vs_serial_emulation,
                             fused_vs_unfused_emulation, generation_speed,
-                            sharded_emulation_probe,
                             sharded_vs_single_emulation)
 
 from .common import append_bench, emit, load_bench, save_json, timed
@@ -47,8 +46,7 @@ def store_warm_vs_cold(quick: bool = False,
     width = 6 if quick else 8
 
     def one_pass() -> Dict:
-        ex = SweepExecutor(apps=apps, emulate_cycles=8, use_pallas=False,
-                           max_workers=2,
+        ex = SweepExecutor(apps=apps, emulate_cycles=8, max_workers=2,
                            store=ResultStore(root))
         t0 = time.perf_counter()
         sweep_num_tracks(tracks, width=width, height=width, executor=ex)
@@ -95,7 +93,7 @@ def search_vs_grid(quick: bool = False) -> Dict:
     grid_root = tempfile.mkdtemp(prefix="canal-grid-bench-")
     search_root = tempfile.mkdtemp(prefix="canal-search-bench-")
 
-    grid_ex = SweepExecutor(apps=apps, use_pallas=False, max_workers=2,
+    grid_ex = SweepExecutor(apps=apps, max_workers=2,
                             store=ResultStore(grid_root))
     t0 = time.perf_counter()
     grid = sweep_num_tracks(tracks, width=width, height=width,
@@ -110,7 +108,7 @@ def search_vs_grid(quick: bool = False) -> Dict:
                  objective="area",
                  constraints={"min_routability": 1.0},
                  budget=budget, batch_size=2, seed=0, store=search_root,
-                 apps=apps, use_pallas=False, max_workers=2)
+                 apps=apps, max_workers=2)
     search_seconds = time.perf_counter() - t0
     best = res.best("area", {"min_routability": 1.0})
     assert best is not None, "search found no feasible point"
@@ -123,8 +121,7 @@ def search_vs_grid(quick: bool = False) -> Dict:
                    objective="area",
                    constraints={"min_routability": 1.0},
                    budget=budget, batch_size=2, seed=0,
-                   store=search_root, apps=apps, use_pallas=False,
-                   max_workers=2)
+                   store=search_root, apps=apps, max_workers=2)
     assert rerun.stats["executor"]["pnr_computations"] == 0, \
         "repeated identical search must be pure store hits"
 
@@ -150,15 +147,14 @@ def run(quick: bool = False):
             f"lower={r['lower_seconds'] * 1e3:.0f}ms"))
 
     # batched configuration emulation: the production run_batch path
-    # (fused batched kernel under use_pallas) vs looping run per config
+    # (the fused XLA engine) vs looping run per config
     batch = 4 if quick else 8
     cycles = 8 if quick else 16
     width = 4 if quick else 6
     tracks = 2 if quick else 4
     emu = batched_vs_serial_emulation(width=width, height=width,
                                       num_tracks=tracks,
-                                      batch=batch, cycles=cycles,
-                                      use_pallas=True)
+                                      batch=batch, cycles=cycles)
     lines.append(emit(
         f"dse_speed/batched_emulation_b={emu['batch']}",
         emu["batched_seconds"] * 1e6,
@@ -170,11 +166,11 @@ def run(quick: bool = False):
     assert emu["batched_seconds"] <= emu["serial_seconds"] * 1.5, \
         "batched DSE emulation must not be slower than the serial baseline"
 
-    # fused engine (one kernel call per cycle, PE cores in-kernel,
+    # fused engine (one fused step per cycle, PE cores inside it,
     # per-config depth masking) vs the sweep-at-a-time PR-1 baseline
     fus = fused_vs_unfused_emulation(width=width, height=width,
                                      num_tracks=tracks, batch=batch,
-                                     cycles=cycles, use_pallas=True)
+                                     cycles=cycles)
     lines.append(emit(
         f"dse_speed/fused_emulation_b={fus['batch']}",
         fus["fused_seconds"] * 1e6,
@@ -187,11 +183,10 @@ def run(quick: bool = False):
     assert fus["fused_seconds"] <= fus["unfused_seconds"] * 1.2, \
         "fused DSE engine must not regress the sweep-at-a-time baseline"
 
-    # batch-axis sharding: in-process (1 device on CI -> fallback parity
-    # check) plus a subprocess probe with forced host devices
+    # batch-axis sharding across this process's devices (1 device ->
+    # fallback parity check)
     shd = sharded_vs_single_emulation(width=4, height=4, num_tracks=2,
-                                      batch=batch, cycles=cycles,
-                                      use_pallas=True)
+                                      batch=batch, cycles=cycles)
     lines.append(emit(
         f"dse_speed/sharded_emulation_dev={shd['devices']}",
         shd["sharded_seconds"] * 1e6,
@@ -203,21 +198,6 @@ def run(quick: bool = False):
         # the single-device fallback
         assert shd["sharded_seconds"] <= shd["single_seconds"] * 1.5, \
             "single-device shard fallback must not add overhead"
-    probe = sharded_emulation_probe(devices=2 if quick else 4,
-                                    batch=batch, cycles=4)
-    if "error" in probe:
-        lines.append(emit("dse_speed/sharded_probe", 0.0,
-                          f"skipped: {probe['error'][:120]}"))
-    else:
-        # forced host devices share the same cores, so this reports the
-        # shard_map split working (bit-identical output is asserted in
-        # the child), not a real speedup
-        lines.append(emit(
-            f"dse_speed/sharded_probe_dev={probe['devices']}",
-            probe["sharded_seconds"] * 1e6,
-            f"single={probe['single_seconds'] * 1e3:.0f}ms "
-            f"sharded={probe['sharded_seconds'] * 1e3:.0f}ms "
-            f"speedup={probe['speedup']:.2f}x"))
     # persistent result store: cold (compute + persist) vs warm (served
     # by digest, zero PnR asserted inside)
     wc = store_warm_vs_cold(quick=quick)
@@ -242,7 +222,6 @@ def run(quick: bool = False):
     save_json("dse_speed", {"generation": recs, "batched_emulation": emu,
                             "fused_emulation": fus,
                             "sharded_emulation": shd,
-                            "sharded_probe": probe,
                             "store_warm_vs_cold": wc,
                             "search_vs_grid": sg})
     # repo-root perf trajectory (append-style; one record per run).

@@ -157,8 +157,7 @@ def sweep_speed(quick: bool = False) -> Dict:
     for strategy in ("python", "minplus"):
         # store=False: this benchmark times the router — serving records
         # from a warm store would measure the cache, not the engine
-        ex = SweepExecutor(apps=apps,
-                           emulate_cycles=8, use_pallas=False,
+        ex = SweepExecutor(apps=apps, emulate_cycles=8,
                            route_strategy=strategy, max_workers=2,
                            store=False)
         t0 = time.perf_counter()

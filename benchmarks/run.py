@@ -38,6 +38,10 @@ def main() -> None:
                     help="run every design point cold (no persistence)")
     args = ap.parse_args()
 
+    from repro.core import compile_cache
+    compile_cache.enable(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+
     # the sweep executors attach the store via the env default; setting it
     # here makes every figure benchmark store-backed without threading a
     # store object through each module

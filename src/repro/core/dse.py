@@ -10,9 +10,9 @@ engine behind the paper's "fast design space exploration" claim: it caches
 ``RoutingResources``/``FabricModule`` per interconnect, evaluates
 independent design points concurrently, and emulates every routed app of a
 design point as one batched ``FabricModule.run_batch`` scan — the fused
-batched Pallas kernel (PE cores evaluated in-kernel, per-app depth
-masking) when ``use_pallas=True``, sharded across devices when more than
-one is visible.
+XLA engine (PE cores evaluated in the fused step, per-app depth
+masking; ``use_pallas=True`` swaps in the Pallas kernels, interpret mode
+only), sharded across devices when more than one is visible.
 
 Design points are :class:`repro.core.spec.InterconnectSpec` objects (legacy
 kwargs dicts are canonicalized into specs on entry), and every executor
@@ -91,7 +91,7 @@ class SweepExecutor:
                  alphas: Sequence[float] = _UNSET,
                  split_fifo_ctrl_delay: float = _UNSET,
                  max_workers: Optional[int] = None,
-                 emulate_cycles: int = 0, use_pallas: bool = True,
+                 emulate_cycles: int = 0, use_pallas: bool = False,
                  shard: Optional[bool] = None, seed: int = _UNSET,
                  route_strategy: str = "auto",
                  place_strategy: str = "auto",
@@ -107,6 +107,9 @@ class SweepExecutor:
             "split_fifo_ctrl_delay", split_fifo_ctrl_delay)
         self.max_workers = max_workers
         self.emulate_cycles = emulate_cycles
+        #: emulation engine: the XLA engine (False) compiles everywhere;
+        #: the Pallas fabric kernels run only in interpret mode (Mosaic
+        #: refuses their gathers, see repro.core.lowering._check_engine)
         self.use_pallas = use_pallas
         self.shard = shard
         self.seed = self._folded_knob("seed", seed)
@@ -363,17 +366,19 @@ class SweepExecutor:
                 pool.shutdown(wait=True)
 
     # ----------------------------------------------------- point execution
-    def _emulate_batch(self, fab, routed: List[Tuple[str, Any, Any]],
+    def emulate_routed(self, fab, routed: List[Tuple[str, Any, Any]],
                        device: Any = None,
-                       io_chunk: Optional[int] = None) -> Dict[str, Dict]:
+                       io_chunk: Optional[int] = None
+                       ) -> Dict[str, Tuple[int, Dict]]:
         """Emulate all routed apps of one design point as a single batch.
 
         ``routed``: (name, packed, PnRResult) triples on ``fab``. Drives a
-        common counter stimulus on every app input and records the output
-        checksum — the bulk validation pass of the batched DSE engine.
-        ``device`` pins the batch to one accelerator (the per-device
-        emulation queues of the async pipeline); None keeps the default
-        placement (sharded across devices when enabled).
+        common counter stimulus (1..T) on every app input for
+        ``emulate_cycles`` cycles and returns ``{name: (depth, {io coord:
+        (T,) outputs})}``, depth being the app's fixpoint sweep count.
+        ``device`` pins the batch to one device (the per-device emulation
+        queues of the async pipeline); None keeps the default placement
+        (sharded across devices when enabled).
         """
         import numpy as np
         from repro.fabric import AppEmulator, run_apps_batch
@@ -400,13 +405,20 @@ class SweepExecutor:
         else:
             outs = run_apps_batch(emulators, inputs, T, shard=self.shard,
                                   io_chunk=io_chunk)
-        report: Dict[str, Dict] = {}
-        for name, emu, out in zip(names, emulators, outs):
-            checksum = int(sum(int(np.asarray(v, np.int64).sum())
-                               for v in out.values()) & 0xFFFFFFFF)
-            report[name] = {"depth": emu.depth, "cycles": T,
-                            "out_checksum": checksum}
-        return report
+        return {name: (emu.depth, out)
+                for name, emu, out in zip(names, emulators, outs)}
+
+    def _emulate_batch(self, fab, routed: List[Tuple[str, Any, Any]],
+                       device: Any = None,
+                       io_chunk: Optional[int] = None) -> Dict[str, Dict]:
+        """The bulk validation pass of the batched DSE engine: emulate
+        the routed apps (:meth:`emulate_routed`) and record each app's
+        depth, cycle count and output checksum."""
+        outs = self.emulate_routed(fab, routed, device=device,
+                                   io_chunk=io_chunk)
+        return {name: {"depth": depth, "cycles": self.emulate_cycles,
+                       "out_checksum": out_checksum(out)}
+                for name, (depth, out) in outs.items()}
 
     # -------------------------------------------------- store-backed flow
     def resolve(self, point) -> InterconnectSpec:
@@ -842,6 +854,14 @@ class SweepExecutor:
         return path
 
 
+def out_checksum(out: Dict) -> int:
+    """The record's ``out_checksum``: the sum of every observed output
+    word of one emulated app, as an unsigned 32-bit value."""
+    import numpy as np
+    return int(sum(int(np.asarray(v, np.int64).sum())
+                   for v in out.values()) & 0xFFFFFFFF)
+
+
 def _executor_for(executor: Optional[SweepExecutor],
                   apps: Optional[Dict[str, Callable]],
                   sa_steps: Optional[int]) -> SweepExecutor:
@@ -967,7 +987,7 @@ def generation_speed(sizes: Sequence[int] = (4, 8, 16, 32)) -> List[Dict]:
 
 def batched_vs_serial_emulation(width: int = 6, height: int = 6,
                                 num_tracks: int = 4, batch: int = 8,
-                                cycles: int = 16, use_pallas: bool = True,
+                                cycles: int = 16, use_pallas: bool = False,
                                 seed: int = 0) -> Dict:
     """Micro-DSE: emulate B random fabric configurations serially
     (``run`` per config) vs as one batch (``run_batch``). Returns wall
@@ -1039,7 +1059,7 @@ def _timed_min(fn, repeats: int) -> Tuple[Any, float]:
 
 def fused_vs_unfused_emulation(width: int = 6, height: int = 6,
                                num_tracks: int = 4, batch: int = 8,
-                               cycles: int = 16, use_pallas: bool = True,
+                               cycles: int = 16, use_pallas: bool = False,
                                seed: int = 0, repeats: int = 3) -> Dict:
     """The fused batched engine (whole fixpoint + PE eval in one kernel
     call per cycle) vs the sweep-at-a-time PR-1 baseline (one batched
@@ -1077,7 +1097,7 @@ def fused_vs_unfused_emulation(width: int = 6, height: int = 6,
 
 def sharded_vs_single_emulation(width: int = 5, height: int = 5,
                                 num_tracks: int = 3, batch: int = 8,
-                                cycles: int = 8, use_pallas: bool = True,
+                                cycles: int = 8, use_pallas: bool = False,
                                 seed: int = 0, repeats: int = 3) -> Dict:
     """``run_batch`` with the batch axis shard_map'ed across every visible
     device vs the same workload on one device. Bit-identical outputs
@@ -1109,43 +1129,3 @@ def sharded_vs_single_emulation(width: int = 5, height: int = 5,
             "devices": len(jax.devices()),
             "single_seconds": single_s, "sharded_seconds": sharded_s,
             "speedup": single_s / max(sharded_s, 1e-9)}
-
-
-def sharded_emulation_probe(devices: int = 4, width: int = 4,
-                            height: int = 4, num_tracks: int = 2,
-                            batch: int = 8, cycles: int = 6,
-                            timeout: float = 600.0) -> Dict:
-    """Run :func:`sharded_vs_single_emulation` in a subprocess with
-    ``devices`` forced host platform devices (XLA must see the flag before
-    backend init, which in this process has already happened). Returns the
-    child's record, or ``{"error": ...}`` when the probe cannot run."""
-    import subprocess
-    import sys
-
-    # src root from this module's path (repro may be a namespace package,
-    # whose __file__ is None)
-    src_root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    code = (
-        "import json\n"
-        "from repro.core.dse import sharded_vs_single_emulation\n"
-        f"rec = sharded_vs_single_emulation(width={width}, "
-        f"height={height}, num_tracks={num_tracks}, batch={batch}, "
-        f"cycles={cycles}, use_pallas=False)\n"
-        "print('PROBE_JSON:' + json.dumps(rec))\n")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={devices}"
-                        ).strip()
-    env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
-    try:
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True,
-                             timeout=timeout)
-    except (OSError, subprocess.TimeoutExpired) as e:
-        return {"error": str(e)}
-    for line in out.stdout.splitlines():
-        if line.startswith("PROBE_JSON:"):
-            return json.loads(line[len("PROBE_JSON:"):])
-    return {"error": f"probe exited {out.returncode}: "
-                     f"{out.stderr.strip()[-500:]}"}

@@ -29,7 +29,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.kernels.fabric_step import PE_OPS, pe_alu_candidates
@@ -42,6 +41,22 @@ assert PECore.OPS == PE_OPS, \
 PE_OP_IDS = {op: i for i, op in enumerate(PECore.OPS)}
 
 DepthSpec = Union[int, np.ndarray, jnp.ndarray]
+
+
+def _check_engine(use_pallas: bool) -> None:
+    """Refuse the Pallas fabric engine where it cannot compile.
+
+    Mosaic, the TPU's Pallas compiler, refuses the ``fabric_step``
+    kernels' gathers (the 1-D ``jnp.take`` of ``fabric_sweep*`` and the
+    fused kernels alike: "Only 2D gather is supported"). Fail here, at
+    construction, instead of deep inside lowering on the first step."""
+    if use_pallas and jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            "FabricModule(use_pallas=True) cannot run on TPU: Mosaic "
+            "refuses the 1-D gathers of the repro.kernels.fabric_step "
+            "kernels. Use the XLA engine (use_pallas=False, the default); "
+            "a Mosaic rewrite of the fabric gathers is the 'Emulation "
+            "engine choice' item of ROADMAP.md.")
 
 
 @dataclass
@@ -82,6 +97,7 @@ class FabricModule:
     """
 
     def __init__(self, ic: Interconnect, use_pallas: bool = False):
+        _check_engine(use_pallas)
         self.ic = ic
         self.use_pallas = use_pallas
         self.nodes: List[Node] = list(ic.nodes())
@@ -674,15 +690,15 @@ class FabricModule:
             return self._run_batch_local(c, e, p, d, max_depth, fused,
                                          io_chunk)
 
-        # check_rep=False: shard_map has no replication rule for
+        # check_vma=False: shard_map has no varying-axes rule for
         # pallas_call; every operand/output is explicitly batch-sharded
-        sharded = shard_map(local, mesh=mesh,
-                            in_specs=(spec, spec, spec, spec),
-                            out_specs=spec, check_rep=False)
+        sharded = jax.shard_map(local, mesh=mesh,
+                                in_specs=(spec, spec, spec, spec),
+                                out_specs=spec, check_vma=False)
         out = sharded(pad_b(configs), pad_b(ext),
                       {k: pad_b(v) for k, v in pe_cfgs.items()},
                       jnp.asarray(np.pad(depths_np, (0, pad))))
-        return out[:b]
+        return out[:b] if pad else out
 
     # ------------------------------------------------- combinational depth
     def _selected_src_host(self, config: np.ndarray) -> np.ndarray:

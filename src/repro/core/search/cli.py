@@ -77,8 +77,9 @@ def build_parser() -> argparse.ArgumentParser:
     g.add_argument("--no-store", action="store_true",
                    help="run cold: no persistent memoization")
     g.add_argument("--pallas", action="store_true",
-                   help="emulate with the Pallas kernels (default: "
-                        "pure-JAX interpreter path)")
+                   help="emulate with the Pallas fabric kernels "
+                        "(interpret mode only, refused on TPU; default: "
+                        "the XLA engine)")
     p.add_argument("-o", "--output", default=None, metavar="FILE",
                    help="write the JSON document here (default: "
                         "stdout)")

@@ -42,7 +42,7 @@ def search(base: Optional[InterconnectSpec] = None,
            executor: Any = None, store: Any = None,
            apps: Optional[Dict] = None, emulate_cycles: int = 0,
            selector_options: Optional[Dict] = None,
-           use_pallas: bool = True,
+           use_pallas: bool = False,
            max_workers: Optional[int] = None,
            **executor_kwargs) -> SearchResult:
     """Search-driven design-space exploration over ``InterconnectSpec``

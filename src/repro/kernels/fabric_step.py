@@ -55,7 +55,12 @@ Three kernels share that structure:
     cycles, whose values depend on the sweep count).
 
 Validated in interpret mode against ``ref.fabric_sweep_ref`` /
-``ref.fabric_fused_batch_ref``.
+``ref.fabric_fused_batch_ref``. None of the four compiles for TPU:
+Mosaic refuses the 1-D ``jnp.take`` of the sweep kernels
+(``_gather_lowering_rule``) and the fused kernels' gathers ("Only 2D
+gather is supported"). They run in interpret mode only, and
+``FabricModule(use_pallas=True)`` refuses a TPU backend; the served
+engine is the XLA one, ``ref.fabric_fused_batch_ref``.
 """
 from __future__ import annotations
 

@@ -19,8 +19,14 @@ uses these cost fields as its batched A* lower bounds.
 device-side blocks and stops as soon as the field stops changing, so the
 iteration count adapts to the graph diameter instead of paying the full
 Bellman-Ford ``N - 1`` bound. ``engine="auto"`` runs the Pallas kernel
-where it compiles (TPU) and the jitted dense reference elsewhere — the
-same dispatch convention as the fabric kernels.
+compiled on TPU and the jitted dense reference elsewhere (interpret mode
+would only be slower).
+
+The kernel compiles for TPU v5e (``tests/test_tpu_compile.py``: 1024
+tiles, batches up to 1024). Its scoped VMEM grows with the batch rows of
+a block — 1024 rows in one block asked for 18 MiB of the 16 MiB limit —
+so the batch axis is gridded in blocks of at most ``BATCH_BLOCK`` rows.
+Rows are independent, so the blocking leaves every result unchanged.
 
 Validated in interpret mode against ``ref.minplus_ref`` /
 ``ref.minplus_fixpoint_ref`` and against host Dijkstra in
@@ -36,6 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BLOCK = 128
+BATCH_BLOCK = 256      # batch rows per grid step (scoped-VMEM bound)
 INF = jnp.float32(3.0e38) / 4
 
 
@@ -47,11 +54,11 @@ def _default_interpret() -> bool:
 
 
 def _minplus_kernel(d_ref, w_ref, out_ref):
-    """d: (B, BLOCK_i) costs; w: (BLOCK_i, BLOCK_j); out: (B, BLOCK_j).
+    """d: (BB, BLOCK_i) costs; w: (BLOCK_i, BLOCK_j); out: (BB, BLOCK_j).
 
     Accumulates the running minimum across the i-grid dimension.
     """
-    i = pl.program_id(1)
+    i = pl.program_id(2)
     d = d_ref[...]                              # (B, bi)
     w = w_ref[...]                              # (bi, bj)
     cand = jnp.min(d[:, :, None] + w[None, :, :], axis=1)   # (B, bj)
@@ -74,21 +81,26 @@ def minplus_step(d: jnp.ndarray, w: jnp.ndarray,
     """
     b, n = d.shape
     n_pad = pl.cdiv(n, BLOCK) * BLOCK
-    d_p = jnp.pad(d, ((0, 0), (0, n_pad - n)), constant_values=INF)
+    # one block holds the whole batch when it fits, else BATCH_BLOCK-row
+    # blocks (a multiple of the 8-row sublane tile) over a padded batch
+    bb = b if b <= BATCH_BLOCK else BATCH_BLOCK
+    b_pad = pl.cdiv(b, bb) * bb
+    d_p = jnp.pad(d, ((0, b_pad - b), (0, n_pad - n)), constant_values=INF)
     w_p = jnp.pad(w, ((0, n_pad - n), (0, n_pad - n)), constant_values=INF)
-    grid = (n_pad // BLOCK, n_pad // BLOCK)     # (j, i): i inner accumulates
+    # (rows, j, i): i innermost accumulates the running minimum
+    grid = (b_pad // bb, n_pad // BLOCK, n_pad // BLOCK)
     out = pl.pallas_call(
         _minplus_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((b, BLOCK), lambda j, i: (0, i)),
-            pl.BlockSpec((BLOCK, BLOCK), lambda j, i: (i, j)),
+            pl.BlockSpec((bb, BLOCK), lambda r, j, i: (r, i)),
+            pl.BlockSpec((BLOCK, BLOCK), lambda r, j, i: (i, j)),
         ],
-        out_specs=pl.BlockSpec((b, BLOCK), lambda j, i: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((b, n_pad), jnp.float32),
+        out_specs=pl.BlockSpec((bb, BLOCK), lambda r, j, i: (r, j)),
+        out_shape=jax.ShapeDtypeStruct((b_pad, n_pad), jnp.float32),
         interpret=interpret,
     )(d_p, w_p)
-    return jnp.minimum(d, out[:, :n])
+    return jnp.minimum(d, out[:b, :n])
 
 
 def minplus_fixpoint(d0: jnp.ndarray, w: jnp.ndarray, iters: int,

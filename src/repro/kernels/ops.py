@@ -1,9 +1,14 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On CPU (this container) kernels run in interpret mode; on TPU the same
-``pallas_call`` lowers to Mosaic. ``interpret`` is resolved from the
-backend on every call, so a mid-process platform swap (tests forcing
-``jax.default_backend``) picks the right mode.
+Off TPU the kernels run in interpret mode; on TPU ``pallas_call`` lowers
+through Mosaic. ``interpret`` is resolved from the backend on every call,
+so a mid-process platform swap (tests forcing ``jax.default_backend``)
+picks the right mode.
+
+Mosaic compiles ``minplus_*``, ``hpwl`` and ``net_bboxes`` for TPU v5e
+(``tests/test_tpu_compile.py``). It refuses the four ``fabric_*``
+kernels (their gathers), so on TPU these wrappers are unreachable from
+the fabric model, which emulates with the XLA engine.
 """
 from __future__ import annotations
 

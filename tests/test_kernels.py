@@ -128,7 +128,7 @@ def test_pack_nets_overflow():
         pack_nets(pin_net, pin_xy, n_nets=1, k_max=2)
 
 
-@pytest.mark.parametrize("n,b", [(64, 1), (200, 4), (300, 2)])
+@pytest.mark.parametrize("n,b", [(64, 1), (200, 4), (300, 2), (130, 300)])
 def test_minplus(n, b):
     rng = np.random.default_rng(n + b)
     d = jnp.asarray((rng.random((b, n)) * 10).astype(np.float32))
@@ -138,6 +138,23 @@ def test_minplus(n, b):
     np.testing.assert_allclose(np.asarray(ops.minplus_step(d, w)),
                                np.asarray(ref.minplus_ref(d, w)),
                                rtol=1e-5)
+
+
+def test_minplus_batch_blocks_leave_rows_unchanged():
+    """A batch over BATCH_BLOCK rows is gridded in row blocks; every row
+    still equals the same row relaxed on its own, bit for bit."""
+    from repro.kernels.minplus import BATCH_BLOCK
+
+    n, b = 130, BATCH_BLOCK + 44
+    rng = np.random.default_rng(3)
+    d = (rng.random((b, n)) * 10).astype(np.float32)
+    w = np.where(rng.random((n, n)) < 0.05, rng.random((n, n)) * 3, 1e30)
+    np.fill_diagonal(w, 0.0)
+    w = jnp.asarray(w.astype(np.float32))
+    whole = np.asarray(ops.minplus_step(jnp.asarray(d), w))
+    for row in (0, BATCH_BLOCK - 1, BATCH_BLOCK, b - 1):
+        alone = np.asarray(ops.minplus_step(jnp.asarray(d[row:row + 1]), w))
+        np.testing.assert_array_equal(whole[row], alone[0])
 
 
 def test_minplus_fixpoint_is_shortest_path():

@@ -92,3 +92,18 @@ def test_pallas_fabric_sweep_matches_xla(small_ic):
     a = np.asarray(fab_ref.run(config, jnp.asarray(ext), depth=10))
     b = np.asarray(fab_pal.run(config, jnp.asarray(ext), depth=10))
     assert np.array_equal(a, b)
+
+
+def test_pallas_fabric_engine_refused_on_tpu(small_ic, monkeypatch):
+    """Mosaic refuses the fabric kernels' gathers: on a TPU backend the
+    Pallas engine fails at construction with an error that says why and
+    names the way out, while the XLA engine builds as usual."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError,
+                       match=r"Mosaic(.|\n)*use_pallas=False(.|\n)*"
+                             r"Emulation engine choice"):
+        compile_interconnect(small_ic, use_pallas=True)
+    assert not compile_interconnect(small_ic, use_pallas=False).use_pallas
+
