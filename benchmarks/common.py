@@ -4,10 +4,9 @@ from __future__ import annotations
 import json
 import os
 import time
-from typing import Any, Callable, Dict
+from typing import Any, Callable
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results")
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def timed(fn: Callable[[], Any]) -> tuple:
@@ -28,53 +27,3 @@ def save_json(name: str, payload: Any) -> str:
     with open(path, "w") as f:
         json.dump(payload, f, indent=2, default=str)
     return path
-
-
-def append_bench(name: str, record: Dict) -> str:
-    """Append one timestamped record to the repo-root ``<name>.json``
-    trajectory file (a JSON list that grows run over run — the
-    append-style perf history the roadmap tracks, as opposed to the
-    overwritten snapshots under ``benchmarks/results/``). A corrupt or
-    non-list file is restarted rather than crashing the benchmark.
-
-    The write is atomic (unique same-directory temp file + ``os.replace``)
-    so readers never see a torn file; the read-modify-write itself is not
-    locked, so two benchmark runs racing on the same trajectory resolve
-    last-writer-wins (one appended record may be dropped)."""
-    from repro.core.store import atomic_write_json
-
-    path = os.path.join(REPO_ROOT, f"{name}.json")
-    try:
-        with open(path) as f:
-            history = json.load(f)
-        if not isinstance(history, list):
-            history = [history]
-    except (OSError, json.JSONDecodeError):
-        history = []
-    history.append(dict(record, ts=time.time()))
-    atomic_write_json(path, history)
-    return path
-
-
-def load_bench(name: str, metric: str = None) -> list:
-    """Read a repo-root trajectory written by :func:`append_bench`.
-
-    Without ``metric``: the full record list ([] when the file is
-    missing or corrupt — consumers must tolerate a restarted
-    trajectory). With ``metric``: that field's value per record, with
-    ``None``/missing values *skipped* — a null metric marks a run where
-    the measurement was meaningless (e.g. ``store_warm_speedup`` on a
-    warm-first-pass run) and must not pollute medians or regression
-    gates."""
-    path = os.path.join(REPO_ROOT, f"{name}.json")
-    try:
-        with open(path) as f:
-            history = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        return []
-    if not isinstance(history, list):
-        history = [history]
-    if metric is None:
-        return history
-    return [r[metric] for r in history
-            if isinstance(r, dict) and r.get(metric) is not None]

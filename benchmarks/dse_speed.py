@@ -5,8 +5,8 @@ serial per-config baseline, the fused engine (whole fixpoint + PE eval
 per cycle) vs the sweep-at-a-time PR-1 path, batch-axis sharding across
 the devices of this process, and the
 spec-addressed persistent result store: the same track sweep cold
-(computing + persisting) vs warm (served from the store, zero PnR) —
-appended to the repo-root ``BENCH_dse.json`` trajectory."""
+(computing + persisting) vs warm (served from the store, zero PnR).
+Results go to ``benchmarks/results/dse_speed.json``."""
 from __future__ import annotations
 
 import os
@@ -19,7 +19,7 @@ from repro.core.dse import (batched_vs_serial_emulation,
                             fused_vs_unfused_emulation, generation_speed,
                             sharded_vs_single_emulation)
 
-from .common import append_bench, emit, load_bench, save_json, timed
+from .common import emit, save_json, timed
 
 
 def store_warm_vs_cold(quick: bool = False,
@@ -224,31 +224,4 @@ def run(quick: bool = False):
                             "sharded_emulation": shd,
                             "store_warm_vs_cold": wc,
                             "search_vs_grid": sg})
-    # repo-root perf trajectory (append-style; one record per run).
-    # A warm first pass makes the cold/warm speedup meaningless (~1x
-    # noise next to real ~3000x measurements): record null so
-    # trajectory consumers (load_bench skips nulls) never average it in.
-    append_bench("BENCH_dse", {
-        "quick": quick,
-        "batched_speedup": emu["speedup"],
-        "fused_speedup": fus["speedup"],
-        "store_cold_seconds": wc["cold_seconds"],
-        "store_warm_seconds": wc["warm_seconds"],
-        "store_warm_speedup": (None if wc["first_pass_was_warm"]
-                               else wc["speedup"]),
-        "store_first_pass_was_warm": wc["first_pass_was_warm"],
-        "search_evaluations": sg["search_evaluations"],
-        "search_grid_size": sg["grid_size"],
-        "search_matched_best": sg["search_matched_best"],
-        "search_seconds": sg["search_seconds"],
-        "grid_seconds": sg["grid_seconds"],
-    })
-    speedups = sorted(load_bench("BENCH_dse", "store_warm_speedup"))
-    if speedups:
-        lines.append(emit(
-            "dse_speed/store_warm_trajectory",
-            0.0,
-            f"n={len(speedups)} "
-            f"median={speedups[len(speedups) // 2]:.0f}x "
-            "(warm-first-pass nulls skipped)"))
     return lines

@@ -1,6 +1,6 @@
 """PnR speed: the device-accelerated PathFinder vs the Python A* oracle.
 
-Three measurements, persisted as ``BENCH_pnr.json``:
+Three measurements, saved as ``benchmarks/results/pnr_speed.json``:
 
 * ``routing`` — routed nets/sec on a shared placement of the benchmark
   apps over a >=8x8 mesh with >=5 tracks: ``strategy="python"``
@@ -22,7 +22,7 @@ from __future__ import annotations
 import time
 from typing import Dict, List
 
-from .common import append_bench, emit, save_json
+from .common import emit, save_json
 
 
 def _route_workload(width: int, height: int, num_tracks: int,
@@ -210,17 +210,6 @@ def run(quick: bool = False):
         f"python={sweep_rec['python']['seconds']:.2f}s "
         f"minplus={sweep_rec['minplus']['seconds']:.2f}s "
         f"speedup={sweep_rec['speedup']:.2f}x"))
-    save_json("BENCH_pnr", {"routing": route_rec, "placement": place_rec,
-                            "sweep": sweep_rec})
-    # repo-root perf trajectory (append-style; one record per run)
-    append_bench("BENCH_pnr", {
-        "route_speedup": route_rec["speedup"],
-        "minplus_nets_per_sec": route_rec["minplus"]["nets_per_sec"],
-        "python_nets_per_sec": route_rec["python"]["nets_per_sec"],
-        "place_speedup": place_rec["speedup"],
-        "place_cost_ratio": place_rec["cost_ratio"],
-        "batched_steps_per_sec": place_rec["batched"]["steps_per_sec"],
-        "sweep_speedup": sweep_rec["speedup"],
-        "sweep_minplus_seconds": sweep_rec["minplus"]["seconds"],
-    })
+    save_json("pnr_speed", {"routing": route_rec, "placement": place_rec,
+                             "sweep": sweep_rec})
     return lines

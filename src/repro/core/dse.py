@@ -41,6 +41,7 @@ import warnings
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from . import trace
 from .area import connection_box_area, switch_box_area
 from .pnr import place_and_route
 from .pnr.app import BENCH_APPS
@@ -216,7 +217,8 @@ class SweepExecutor:
             ic = self._ic_cache.get(key)
         if ic is None:
             from .passes import PassManager
-            ic = PassManager().run(spec.hardware_spec())
+            with trace.span("hwgen.compile"):
+                ic = PassManager().run(spec.hardware_spec())
             with self._lock:
                 ic = self._ic_cache.setdefault(key, ic)
         return ic
@@ -235,7 +237,8 @@ class SweepExecutor:
         if report is None:
             if ic is None:
                 ic = self.interconnect(spec)
-            report = analyze(ic, spec=spec.hardware_spec())
+            with trace.span("hwgen.analyze"):
+                report = analyze(ic, spec=spec.hardware_spec())
             with self._lock:
                 report = self._analysis_cache.setdefault(key, report)
         return report
@@ -252,7 +255,8 @@ class SweepExecutor:
         with self._lock:
             res = self._res_cache.get(ckey)
         if res is None:
-            res = RoutingResources(ic, reg_penalty=rp)
+            with trace.span("route.resources"):
+                res = RoutingResources(ic, reg_penalty=rp)
             with self._lock:
                 res = self._res_cache.setdefault(ckey, res)
         return res
@@ -414,8 +418,9 @@ class SweepExecutor:
         """The bulk validation pass of the batched DSE engine: emulate
         the routed apps (:meth:`emulate_routed`) and record each app's
         depth, cycle count and output checksum."""
-        outs = self.emulate_routed(fab, routed, device=device,
-                                   io_chunk=io_chunk)
+        with trace.span("emulate.batch"):
+            outs = self.emulate_routed(fab, routed, device=device,
+                                       io_chunk=io_chunk)
         return {name: {"depth": depth, "cycles": self.emulate_cycles,
                        "out_checksum": out_checksum(out)}
                 for name, (depth, out) in outs.items()}
@@ -517,7 +522,8 @@ class SweepExecutor:
 
     def _store_put(self, spec: InterconnectSpec, rec: Dict) -> None:
         if self.store is not None:
-            self.store.put(spec, rec)
+            with trace.span("store.put"):
+                self.store.put(spec, rec)
 
     def run_point(self, point,
                   extra: Optional[Dict] = None,
@@ -590,8 +596,9 @@ class SweepExecutor:
             emu_fut = None
             rec = None if assume_cold else self._store_lookup(digest)
             if rec is None:
-                rec, emu_fut = self._compute_point(
-                    spec, digest, defer_emulation, pending)
+                with trace.span("point", tag=digest):
+                    rec, emu_fut = self._compute_point(
+                        spec, digest, defer_emulation, pending)
             fut.set_result((rec, emu_fut))
         except BaseException as e:
             fut.set_exception(e)
@@ -676,13 +683,14 @@ class SweepExecutor:
         routed: List[Tuple[str, Any, Any]] = []
         for name, mk in self.apps.items():
             app = mk()
-            r = place_and_route(
-                ic, app, alphas=spec.alphas, sa_steps=spec.sa_steps,
-                sa_batch=spec.sa_batch, resources=res, seed=spec.seed,
-                split_fifo_ctrl_delay=spec.split_fifo_ctrl_delay,
-                route_strategy=spec.route_strategy,
-                auto_min_tiles=spec.auto_min_tiles,
-                place_strategy=spec.place_strategy)
+            with trace.span("pnr", app=name):
+                r = place_and_route(
+                    ic, app, alphas=spec.alphas, sa_steps=spec.sa_steps,
+                    sa_batch=spec.sa_batch, resources=res, seed=spec.seed,
+                    split_fifo_ctrl_delay=spec.split_fifo_ctrl_delay,
+                    route_strategy=spec.route_strategy,
+                    auto_min_tiles=spec.auto_min_tiles,
+                    place_strategy=spec.place_strategy)
             out[name] = {
                 "success": r.success,
                 "critical_path_ns": r.timing.get("critical_path_ns",
@@ -702,12 +710,13 @@ class SweepExecutor:
                 # metrics from the merged population)
                 from .analysis import analyze as run_rules
                 from .analysis import routed_static_metrics
-                routed_rep = run_rules(ic, spec=spec.hardware_spec(),
-                                       scope="routed", pnr=r)
-                out[name]["routed_analysis"] = routed_rep.to_dict(
-                    max_diagnostics=4)
-                out[name].update(routed_static_metrics(
-                    r.packed, r.routing, r.placement))
+                with trace.span("hwgen.routed", app=name):
+                    routed_rep = run_rules(ic, spec=spec.hardware_spec(),
+                                           scope="routed", pnr=r)
+                    out[name]["routed_analysis"] = routed_rep.to_dict(
+                        max_diagnostics=4)
+                    out[name].update(routed_static_metrics(
+                        r.packed, r.routing, r.placement))
             if r.success and self.emulate_cycles:
                 routed.append((name, r.packed, r))
         rec: Dict = {"spec_digest": digest,

@@ -429,6 +429,7 @@ class FabricModule:
                   if self.num_io else jnp.zeros(0, jnp.int32))
         return new_state, io_obs
 
+    @jax.named_scope("canal.emulate")
     def run(self, config: jnp.ndarray, ext_stream: jnp.ndarray,
             pe_cfg: Optional[Dict[str, jnp.ndarray]] = None,
             depth: Optional[int] = None) -> jnp.ndarray:
@@ -599,6 +600,7 @@ class FabricModule:
             n_io=self.num_io, n_mem=self.num_mem, max_depth=max_depth,
             chunk=io_chunk, word=WORD)
 
+    @jax.named_scope("canal.emulate")
     def _run_batch_local(self, configs: jnp.ndarray, ext: jnp.ndarray,
                          pe_cfgs: Dict[str, jnp.ndarray],
                          depths: jnp.ndarray, max_depth: int,

@@ -37,6 +37,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.core import trace
 from repro.kernels import ops
 
 from .packing import PackedGraph
@@ -163,6 +164,7 @@ def eq2_cost(packed: PackedGraph, placement: Dict[str, Tuple[int, int]],
 @functools.partial(
     jax.jit,
     static_argnames=("n_steps", "n_chains", "cands", "exchange_every"))
+@jax.named_scope("canal.anneal")
 def _anneal(slot_xy, mov_gid, inst_lo, inst_size, net_pins, net_mask,
             mov_nets, pos0, occ0, slot0, owner0, bbox0,
             seed, gamma, alpha, t0, t_min, ladder,
@@ -371,8 +373,9 @@ def batched_place(packed: PackedGraph,
     # seed the chain state with the full per-net reduction — the Pallas
     # HPWL/bbox kernel on the padded (n_nets, K, 2) pin table
     pins0 = pos0[net_pins]
-    bbox0 = np.asarray(ops.net_bboxes(jnp.asarray(pins0),
-                                      jnp.asarray(net_mask)))
+    bbox0 = ops.net_bboxes(jnp.asarray(pins0), jnp.asarray(net_mask))
+    with trace.span("device.wait"):
+        bbox0 = np.asarray(bbox0)
     bbox0 = np.concatenate([bbox0, np.zeros((1, 4), np.int32)])
 
     best_slot, best_cost = _anneal(
@@ -385,8 +388,9 @@ def batched_place(packed: PackedGraph,
         jnp.float32(t0), jnp.float32(t_min), jnp.float32(ladder),
         n_steps=int(n_steps), n_chains=int(n_chains), cands=int(cands),
         exchange_every=int(exchange_every))
-    best_slot = np.asarray(best_slot)
-    best_cost = np.asarray(best_cost)
+    with trace.span("device.wait"):
+        best_slot = np.asarray(best_slot)
+        best_cost = np.asarray(best_cost)
     win = int(np.argmin(best_cost))
 
     out = {n: (int(x), int(y)) for n, (x, y) in placement.items()}

@@ -8,6 +8,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 
+from repro.core import trace
 from repro.core.graph import Interconnect, Node
 from .app import AppGraph
 from .packing import PackedGraph, pack
@@ -81,12 +82,16 @@ def place_and_route(ic: Interconnect, app: AppGraph,
     io_ring = bool(getattr(ic, "spec", None).io_ring
                    if getattr(ic, "spec", None) else True)
 
-    packed = pack(app)
-    fixed = assign_ios(packed, W, H)
-    cont = global_place(packed, W, H, mem_columns=mem_cols, fixed=fixed,
-                        seed=seed)
-    base_pl = legalize(packed, cont, W, H, mem_columns=mem_cols,
-                       io_ring=io_ring, fixed=fixed)
+    with trace.span("place.pack"):
+        packed = pack(app)
+    with trace.span("place.io"):
+        fixed = assign_ios(packed, W, H)
+    with trace.span("place.global"):
+        cont = global_place(packed, W, H, mem_columns=mem_cols,
+                            fixed=fixed, seed=seed)
+    with trace.span("place.legalize"):
+        base_pl = legalize(packed, cont, W, H, mem_columns=mem_cols,
+                           io_ring=io_ring, fixed=fixed)
     if resources is None:
         resources = RoutingResources(ic)
 
@@ -97,21 +102,28 @@ def place_and_route(ic: Interconnect, app: AppGraph,
     best: Optional[PnRResult] = None
     last_err = ""
     for alpha in alphas:
-        pl = detailed_place(packed, base_pl, W, H, mem_columns=mem_cols,
-                            io_ring=io_ring, gamma=gamma, alpha=alpha,
-                            n_steps=sa_steps, batch=sa_batch, seed=seed,
-                            strategy=place_strat)
+        with trace.span("place.detailed", alpha=alpha,
+                        engine=place_strat):
+            pl = detailed_place(packed, base_pl, W, H,
+                                mem_columns=mem_cols, io_ring=io_ring,
+                                gamma=gamma, alpha=alpha, n_steps=sa_steps,
+                                batch=sa_batch, seed=seed,
+                                strategy=place_strat)
         try:
-            routing = route_app(ic, packed, pl, max_iters=route_iters,
-                                res=resources, seed=seed,
-                                strategy=route_strategy,
-                                auto_min_tiles=auto_min_tiles)
+            with trace.span("route.app", alpha=alpha,
+                            engine=route_strategy) as s:
+                routing = route_app(ic, packed, pl, max_iters=route_iters,
+                                    res=resources, seed=seed,
+                                    strategy=route_strategy,
+                                    auto_min_tiles=auto_min_tiles)
+                s.set(engine=routing.strategy, rounds=routing.iterations)
         except RoutingError as e:
             last_err = str(e)
             continue
-        timing = sta_critical_path(
-            packed, routing, pl,
-            split_fifo_ctrl_delay=split_fifo_ctrl_delay)
+        with trace.span("sta"):
+            timing = sta_critical_path(
+                packed, routing, pl,
+                split_fifo_ctrl_delay=split_fifo_ctrl_delay)
         cand = PnRResult(
             success=True, placement=pl, packed=packed, routing=routing,
             timing=timing, alpha=alpha,

@@ -65,6 +65,7 @@ import numpy as np
 
 _log = logging.getLogger(__name__)
 
+from repro.core import trace
 from repro.core.graph import (Interconnect, Node, NodeKind)
 from .packing import PackedGraph
 
@@ -311,8 +312,9 @@ class CoarseGraph:
                 bucket *= 2
             d0 = np.full((bucket, self.n_tiles), COARSE_INF, np.float32)
             d0[np.arange(len(missing)), missing] = 0.0
-            out = np.asarray(kops.minplus_wavefront(
-                d0, w.T.astype(np.float32)), np.float64)
+            relaxed = kops.minplus_wavefront(d0, w.T.astype(np.float32))
+            with trace.span("device.wait"):
+                out = np.asarray(relaxed, np.float64)
             for row, t in zip(out, missing):
                 rows[t] = row
                 if zero_hist:

@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core import trace
+
 BLOCK = 128
 BATCH_BLOCK = 256      # batch rows per grid step (scoped-VMEM bound)
 INF = jnp.float32(3.0e38) / 4
@@ -73,6 +75,7 @@ def _minplus_kernel(d_ref, w_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.named_scope("canal.minplus")
 def minplus_step(d: jnp.ndarray, w: jnp.ndarray,
                  interpret: bool = True) -> jnp.ndarray:
     """One relaxation: returns min(d, d ⊗ w) for batched cost vectors.
@@ -114,6 +117,7 @@ def minplus_fixpoint(d0: jnp.ndarray, w: jnp.ndarray, iters: int,
 
 
 @functools.partial(jax.jit, static_argnames=("iters",))
+@jax.named_scope("canal.minplus")
 def _ref_block(d: jnp.ndarray, w: jnp.ndarray, iters: int) -> jnp.ndarray:
     """``iters`` dense relaxations of the pure-jnp oracle under one jit."""
 
@@ -153,7 +157,9 @@ def minplus_wavefront(d0: jnp.ndarray, w: jnp.ndarray,
             nd = minplus_fixpoint(d, w, block_iters, interpret=interpret)
         else:
             nd = _ref_block(d, w, block_iters)
-        if bool(jnp.array_equal(nd, d)):
+        with trace.span("device.wait"):
+            done = bool(jnp.array_equal(nd, d))
+        if done:
             return nd
         d = nd
     return d

@@ -36,6 +36,7 @@ import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Sequence, Union
 
+from repro.core import trace
 from repro.core.dse import SweepExecutor
 from repro.core.spec import InterconnectSpec
 from repro.core.store import ResultStore
@@ -84,7 +85,8 @@ class DSEService:
         single = isinstance(request, (InterconnectSpec, dict))
         reqs = [request] if single else list(request)
         t0 = time.perf_counter()
-        recs = self._query_batch(reqs)
+        with trace.span("serve.query"):
+            recs = self._query_batch(reqs)
         dt = time.perf_counter() - t0
         with self._lock:
             self.queries += 1
@@ -186,13 +188,15 @@ class DSEService:
         handed to ``run_points(..., assume_cold=True)``, which trusts
         this verdict instead of probing again — each cold point hits
         the store exactly once."""
-        return self.executor.probe(digest)
+        with trace.span("serve.probe"):
+            return self.executor.probe(digest)
 
     # ---------------------------------------------------------------- async
     def submit(self, request: Request) -> Future:
         """Asynchronous :meth:`query`: returns a
         :class:`concurrent.futures.Future` resolving to the record(s)."""
-        return self._pool.submit(self.query, request)
+        return self._pool.submit(trace.handoff("serve.queue", self.query),
+                                 request)
 
     async def query_async(self, request: Request):
         """:meth:`query` bridged into asyncio (awaitable)."""
@@ -242,11 +246,16 @@ class DSEService:
         return {"requested": len(requests), "already_warm": delta}
 
     def stats(self) -> Dict[str, Any]:
+        """Counters and query latency; while a trace recording is on
+        (:func:`repro.core.trace.recording`), ``"spans"`` holds its
+        per-span-name summary."""
         # the store scan (an os.listdir walk for the record count) runs
         # outside the query lock: stats polling on a large store must
         # not serialize the query path behind disk I/O
         store_stats = (self.store.stats() if self.store is not None
                        else None)
+        rec = trace.active()
+        spans = {"spans": rec.summary()} if rec is not None else {}
         with self._lock:
             q = max(self.queries, 1)
             return {
@@ -259,6 +268,7 @@ class DSEService:
                 "latency_max_s": self._latency_max,
                 "executor": self.executor.stats(),
                 "store": store_stats,
+                **spans,
             }
 
     def close(self) -> None:

@@ -416,31 +416,6 @@ def test_cli_usage_errors(tmp_path):
     assert e.value.code == 2
 
 
-def test_load_bench_skips_null_metrics(tmp_path, monkeypatch):
-    """Trajectory consumers must skip null metric values: a
-    warm-first-pass run records ``store_warm_speedup: null`` (its ~1x
-    'speedup' is meaningless next to real cold/warm measurements) and
-    must not pollute medians."""
-    import importlib.util
-    import os
-    path = os.path.join(os.path.dirname(__file__), os.pardir,
-                        "benchmarks", "common.py")
-    spec = importlib.util.spec_from_file_location("_bench_common", path)
-    common = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(common)
-    monkeypatch.setattr(common, "REPO_ROOT", str(tmp_path))
-    (tmp_path / "BENCH_x.json").write_text(json.dumps([
-        {"store_warm_speedup": 3000.0, "quick": True},
-        {"store_warm_speedup": None, "quick": True},
-        {"quick": True},
-        {"store_warm_speedup": 2000.0, "quick": False}]))
-    assert common.load_bench("BENCH_x", "store_warm_speedup") == \
-        [3000.0, 2000.0]
-    assert len(common.load_bench("BENCH_x")) == 4
-    assert common.load_bench("BENCH_missing") == []
-    assert common.load_bench("BENCH_missing", "anything") == []
-
-
 def test_canal_front_door_exports():
     assert canal.search is not None and canal.SearchSpace is not None
     assert "search" in canal.__all__ and "SearchSpace" in canal.__all__
