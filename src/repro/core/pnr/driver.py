@@ -12,7 +12,8 @@ from repro.core import trace
 from repro.core.graph import Interconnect, Node
 from .app import AppGraph
 from .packing import PackedGraph, pack
-from .global_place import assign_ios, global_place, legalize
+from .global_place import (assign_ios, global_place, legalize,
+                           solver_programs)
 from .detailed_place import detailed_place, resolve_place_strategy
 from .route import (RoutingError, RoutingResources, RoutingResult, route_app)
 from .timing import sta_critical_path
@@ -86,9 +87,10 @@ def place_and_route(ic: Interconnect, app: AppGraph,
         packed = pack(app)
     with trace.span("place.io"):
         fixed = assign_ios(packed, W, H)
-    with trace.span("place.global"):
+    with trace.span("place.global") as s:
         cont = global_place(packed, W, H, mem_columns=mem_cols,
                             fixed=fixed, seed=seed)
+        s.set(programs=solver_programs())
     with trace.span("place.legalize"):
         base_pl = legalize(packed, cont, W, H, mem_columns=mem_cols,
                            io_ring=io_ring, fixed=fixed)

@@ -7,11 +7,13 @@ conjugate gradient method (the paper cites APlace's CG approach). Memory
 legalization is the usual anchor-iteration: each outer round adds springs
 pulling MEM instances to their nearest legal column, then re-solves.
 
-The quadratic solve runs in JAX (matvec + jax.scipy CG), so the placer
-itself is a dense array program.
+The quadratic solve runs in JAX (matvec + jax.scipy CG). All outer
+rounds, memory anchoring included, are one jitted program, compiled once
+per app shape and reused by every later call with that shape.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -44,6 +46,69 @@ def assign_ios(packed: PackedGraph, w: int, h: int) -> Dict[str,
     stride = max(1, len(ring) // max(len(ios), 1))
     return {name: ring[(i * stride) % len(ring)]
             for i, name in enumerate(ios)}
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_nets", "outer_iters", "cg_tol", "anchor_mems"))
+def _global_solve(pin_net: jnp.ndarray, pin_mov: jnp.ndarray,
+                  pin_fix: jnp.ndarray, x0: jnp.ndarray, hi: jnp.ndarray,
+                  is_mem: jnp.ndarray, mem_cols: jnp.ndarray, *,
+                  n_nets: int, outer_iters: int, cg_tol: float,
+                  anchor_mems: bool) -> jnp.ndarray:
+    """All ``outer_iters`` anchor rounds of the quadratic solve.
+
+    pin_net/pin_mov: (n_pins,) net id and movable index (-1: fixed pin);
+    pin_fix: (n_pins, 2) fixed pin positions; x0: (n_mov, 2) start;
+    hi: (2,) clip limits; is_mem: (n_mov,); mem_cols: (n_cols,) sorted.
+    """
+    n_mov = x0.shape[0]
+    segs = max(n_nets, 1)
+    mov = jnp.clip(pin_mov, 0, n_mov - 1)
+    is_mov = (pin_mov >= 0)[:, None]
+    net_size = jax.ops.segment_sum(jnp.ones_like(pin_net, jnp.float32),
+                                   pin_net, num_segments=segs)
+
+    def grad_quadratic(x, anchor_w, anchor_p):
+        """Gradient of Σ_net Σ_pins ||p − c_net||² + Σ anchors, wrt x."""
+        p = jnp.where(is_mov, x[mov], pin_fix)
+        c = (jax.ops.segment_sum(p, pin_net, num_segments=segs)
+             / jnp.maximum(net_size, 1.0)[:, None])
+        resid = p - c[pin_net]
+        g = jnp.zeros_like(x).at[mov].add(jnp.where(is_mov, resid, 0.0))
+        g = g + anchor_w[:, None] * (x - anchor_p)
+        return 2.0 * g
+
+    # The cost is quadratic ⇒ grad is affine in x: solve A x = b with CG,
+    # where A x = grad(x) − grad(0) and b = −grad(0).
+    x = x0
+    anchor_w = jnp.zeros((n_mov,), jnp.float32)
+    anchor_p = jnp.zeros((n_mov, 2), jnp.float32)
+    for outer in range(outer_iters):
+        g0 = grad_quadratic(jnp.zeros_like(x), anchor_w, anchor_p)
+
+        def matvec(v):
+            return (grad_quadratic(v.reshape(n_mov, 2), anchor_w, anchor_p)
+                    - g0).reshape(-1)
+
+        sol, _ = jax_cg(matvec, (-g0).reshape(-1), x0=x.reshape(-1),
+                        tol=cg_tol, maxiter=200)
+        x = jnp.clip(sol.reshape(n_mov, 2), 0.0, hi)
+
+        # MEM_potential: anchor memories to their nearest legal column
+        if anchor_mems:
+            col = mem_cols[jnp.argmin(
+                jnp.abs(x[:, :1] - mem_cols[None, :]), axis=1)]
+            anchor_p = x.at[:, 0].set(jnp.where(is_mem, col, x[:, 0]))
+            anchor_w = jnp.where(is_mem, 0.5 * (outer + 1), 0.0
+                                 ).astype(jnp.float32)
+    return x
+
+
+def solver_programs() -> int:
+    """Compiled programs the global-placement solver holds: one per
+    distinct app shape (movable count, pin count, net count, memory
+    columns) seen by this process."""
+    return _global_solve._cache_size()
 
 
 def global_place(packed: PackedGraph, width: int, height: int,
@@ -88,64 +153,17 @@ def global_place(packed: PackedGraph, width: int, height: int,
                 pin_fix.append((float(fx), float(fy)))
         n_nets += 1
 
-    pin_net_a = jnp.asarray(np.array(pin_net, np.int32))
-    pin_mov_a = jnp.asarray(np.array(pin_mov, np.int32))
-    pin_fix_a = jnp.asarray(np.array(pin_fix, np.float32))
-    net_size = jax.ops.segment_sum(jnp.ones_like(pin_net_a, jnp.float32),
-                                   pin_net_a, num_segments=max(n_nets, 1))
-
-    def pin_positions(x: jnp.ndarray) -> jnp.ndarray:
-        """x: (n_mov, 2) -> (n_pins, 2)."""
-        mov_pos = x[jnp.clip(pin_mov_a, 0, n_mov - 1)]
-        return jnp.where((pin_mov_a >= 0)[:, None], mov_pos, pin_fix_a)
-
-    def grad_quadratic(x: jnp.ndarray, anchor_w: jnp.ndarray,
-                       anchor_p: jnp.ndarray) -> jnp.ndarray:
-        """Gradient of Σ_net Σ_pins ||p − c_net||² + Σ anchors, wrt x."""
-        p = pin_positions(x)
-        c = (jax.ops.segment_sum(p, pin_net_a, num_segments=max(n_nets, 1))
-             / jnp.maximum(net_size, 1.0)[:, None])
-        resid = p - c[pin_net_a]
-        g = jnp.zeros_like(x)
-        g = g.at[jnp.clip(pin_mov_a, 0, n_mov - 1)].add(
-            jnp.where((pin_mov_a >= 0)[:, None], resid, 0.0))
-        g = g + anchor_w[:, None] * (x - anchor_p)
-        return 2.0 * g
-
-    # The cost is quadratic ⇒ grad is affine in x: solve A x = b with CG,
-    # where A x = grad(x) − grad(0) and b = −grad(0).
     rng = np.random.default_rng(seed)
-    x = jnp.asarray(
-        rng.uniform([width * .25, height * .25],
-                    [width * .75, height * .75],
-                    size=(n_mov, 2)).astype(np.float32))
-    anchor_w = jnp.zeros((n_mov,), jnp.float32)
-    anchor_p = jnp.zeros((n_mov, 2), jnp.float32)
+    x0 = rng.uniform([width * .25, height * .25],
+                     [width * .75, height * .75],
+                     size=(n_mov, 2)).astype(np.float32)
     mem_cols = np.array(sorted(mem_columns), np.float32)
-
-    for outer in range(outer_iters):
-        g0 = grad_quadratic(jnp.zeros_like(x), anchor_w, anchor_p)
-
-        def matvec(v):
-            return (grad_quadratic(v.reshape(n_mov, 2), anchor_w, anchor_p)
-                    - g0).reshape(-1)
-
-        b = (-g0).reshape(-1)
-        sol, _ = jax_cg(matvec, b, x0=x.reshape(-1), tol=cg_tol, maxiter=200)
-        x = sol.reshape(n_mov, 2)
-        x = jnp.clip(x, 0.0, jnp.asarray([width - 1.0, height - 1.0]))
-
-        # MEM_potential: anchor memories to their nearest legal column
-        if len(mem_cols) and is_mem.any():
-            xx = np.asarray(x)
-            tgt = xx.copy()
-            col = mem_cols[np.argmin(
-                np.abs(xx[:, :1] - mem_cols[None, :]), axis=1)]
-            tgt[:, 0] = np.where(is_mem, col, xx[:, 0])
-            w_new = np.where(is_mem, 0.5 * (outer + 1), 0.0) \
-                .astype(np.float32)
-            anchor_w = jnp.asarray(w_new)
-            anchor_p = jnp.asarray(tgt.astype(np.float32))
+    x = _global_solve(
+        np.array(pin_net, np.int32), np.array(pin_mov, np.int32),
+        np.array(pin_fix, np.float32).reshape(-1, 2), x0,
+        np.array([width - 1.0, height - 1.0], np.float32), is_mem,
+        mem_cols, n_nets=n_nets, outer_iters=outer_iters, cg_tol=cg_tol,
+        anchor_mems=bool(len(mem_cols) and is_mem.any()))
 
     out = {k: (float(px), float(py)) for k, (px, py) in fixed.items()}
     xx = np.asarray(x)

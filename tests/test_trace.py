@@ -169,6 +169,8 @@ def test_cold_served_point_records_every_span(tmp_path):
     assert route.attrs["rounds"] >= 1
     assert by_id[route.parent].name == "pnr"
     assert by_id[route.parent].attrs == {"app": "pointwise"}
+    glob = next(s for s in rec.spans if s.name == "place.global")
+    assert glob.attrs["programs"] >= 1
     detailed = next(s for s in rec.spans if s.name == "place.detailed")
     assert detailed.attrs == {"alpha": 2.0, "engine": "batched"}
     point = next(s for s in rec.spans if s.name == "point")
