@@ -372,13 +372,16 @@ class SweepExecutor:
     # ----------------------------------------------------- point execution
     def emulate_routed(self, fab, routed: List[Tuple[str, Any, Any]],
                        device: Any = None,
-                       io_chunk: Optional[int] = None
+                       io_chunk: Optional[int] = None,
+                       stimulus: Optional[Dict[str, Dict[str, Any]]] = None
                        ) -> Dict[str, Tuple[int, Dict]]:
         """Emulate all routed apps of one design point as a single batch.
 
-        ``routed``: (name, packed, PnRResult) triples on ``fab``. Drives a
-        common counter stimulus (1..T) on every app input for
-        ``emulate_cycles`` cycles and returns ``{name: (depth, {io coord:
+        ``routed``: (name, packed, PnRResult) triples on ``fab``.
+        ``stimulus`` gives, per app name, ``{io_in instance: (T,)
+        words}`` for every app input, all of one length T; without it a
+        common counter (1..T) drives every app input for
+        ``emulate_cycles`` cycles. Returns ``{name: (depth, {io coord:
         (T,) outputs})}``, depth being the app's fixpoint sweep count.
         ``device`` pins the batch to one device (the per-device emulation
         queues of the async pipeline); None keeps the default placement
@@ -389,18 +392,27 @@ class SweepExecutor:
 
         if io_chunk is None:
             io_chunk = self.io_chunk
-        emulators, inputs, names = [], [], []
         T = self.emulate_cycles
-        for name, packed, result in routed:
-            emu = AppEmulator.from_pnr(fab, packed, result)
-            ins = {}
-            for inst_name, inst in packed.placeable.items():
-                if inst.kind == "io_in":
-                    coord = result.placement[inst_name]
-                    ins[coord] = np.arange(1, T + 1, dtype=np.int32)
-            emulators.append(emu)
-            inputs.append(ins)
-            names.append(name)
+        if stimulus is not None:
+            lengths = {len(words) for drive in stimulus.values()
+                       for words in drive.values()}
+            if len(lengths) != 1:
+                raise ValueError("stimulus streams must share one length, "
+                                 f"got {sorted(lengths)}")
+            T, = lengths
+        counter = np.arange(1, T + 1, dtype=np.int32)
+        emulators, inputs, names = [], [], []
+        with trace.span("emulate.bind", apps=len(routed)):
+            for name, packed, result in routed:
+                emulators.append(AppEmulator.from_pnr(fab, packed, result))
+                drive = None if stimulus is None else stimulus[name]
+                inputs.append({
+                    result.placement[inst_name]: (
+                        counter if drive is None
+                        else np.asarray(drive[inst_name], np.int32))
+                    for inst_name, inst in packed.placeable.items()
+                    if inst.kind == "io_in"})
+                names.append(name)
         if device is not None:
             import jax
             with jax.default_device(device):

@@ -59,6 +59,31 @@ def _check_engine(use_pallas: bool) -> None:
             "engine choice' item of ROADMAP.md.")
 
 
+def _delayed_as_immediates(reg_mask, imm_mask, imm_val, pe_regs):
+    """Fold delayed PE ports into the immediates: within a cycle a
+    delayed input is a constant, the value its port captured at the end
+    of the last cycle (``pe_regs``). The fixpoint then needs no change;
+    the caller captures the ports' values after it."""
+    delayed = reg_mask > 0
+    return (jnp.where(delayed, 1, imm_mask),
+            jnp.where(delayed, pe_regs, imm_val))
+
+
+def _refuse_delays_streamed(pe_cfgs: Dict[str, jnp.ndarray]) -> None:
+    """The streamed engine carries no PE input registers across cycles:
+    refuse a program that delays any port rather than answer wrongly."""
+    if "reg_mask" not in pe_cfgs:
+        return
+    try:
+        delayed = bool(np.any(np.asarray(pe_cfgs["reg_mask"])))
+    except jax.errors.TracerArrayConversionError:
+        delayed = True
+    if delayed:
+        raise NotImplementedError(
+            "the streamed engine (io_chunk) does not carry PE input "
+            "registers across cycles; run delayed ports without io_chunk")
+
+
 @dataclass
 class ConfigSlot:
     node_id: int
@@ -104,6 +129,13 @@ class FabricModule:
         self.node_id: Dict[Node, int] = {n: i for i, n in
                                          enumerate(self.nodes)}
         self.config_slots: List[ConfigSlot] = []
+        #: run_batch's program, jitted once: JAX keys it on the static
+        #: loop bound and engine (an eagerly called scan would be traced
+        #: and compiled again on every call)
+        self._run_batch_jit = jax.jit(self._run_batch_local,
+                                      static_argnums=(4, 5, 6))
+        #: its sharded form, one per device tuple
+        self._sharded_programs: Dict[Tuple, object] = {}
         self._build_tables()
         self._build_cores()
 
@@ -271,19 +303,19 @@ class FabricModule:
         return self.arrays.num_config
 
     def init_state(self) -> Dict[str, jnp.ndarray]:
+        """Registers, memories and the PE input registers (``pe_regs``,
+        (P, 4): what each PE input port carried last cycle)."""
         return {
             "regs": jnp.zeros(len(self.arrays.reg_ids), dtype=jnp.int32),
             "mem": jnp.zeros(max(self.num_mem, 1), dtype=jnp.int32),
+            "pe_regs": jnp.zeros((self.fused_tables["num_pe_slots"], 4),
+                                 dtype=jnp.int32),
         }
 
     def init_state_batch(self, batch: int) -> Dict[str, jnp.ndarray]:
         """State for ``batch`` independent configurations (leading B dim)."""
-        return {
-            "regs": jnp.zeros((batch, len(self.arrays.reg_ids)),
-                              dtype=jnp.int32),
-            "mem": jnp.zeros((batch, max(self.num_mem, 1)),
-                             dtype=jnp.int32),
-        }
+        return {k: jnp.broadcast_to(v, (batch,) + v.shape)
+                for k, v in self.init_state().items()}
 
     def default_pe_cfg(self) -> Dict[str, jnp.ndarray]:
         n = max(self.num_pe, 1)
@@ -293,6 +325,9 @@ class FabricModule:
             # per-port packed-constant immediates (packing stage, §3.4)
             "imm_mask": jnp.zeros((n, 4), dtype=jnp.int32),
             "imm_val": jnp.zeros((n, 4), dtype=jnp.int32),
+            # per-port delay mode: the input reads last cycle's value
+            # (Amber's PE input register; packed app registers)
+            "reg_mask": jnp.zeros((n, 4), dtype=jnp.int32),
         }
 
     def default_pe_cfg_batch(self, batch: int) -> Dict[str, jnp.ndarray]:
@@ -382,15 +417,21 @@ class FabricModule:
              depth: int = 16) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
         """One fabric clock cycle.
 
-        state: registers/mem. ext_in: (num_io,) values driven onto io_out
-        ports. config: (num_config,) mux selects. Returns (state', io_out
-        observations). ``depth`` = fixpoint sweeps (≥ longest configured
-        combinational chain).
+        state: registers/mem/PE input registers. ext_in: (num_io,) values
+        driven onto io_out ports. config: (num_config,) mux selects.
+        Returns (state', io_out observations). ``depth`` = fixpoint
+        sweeps (≥ longest configured combinational chain).
         """
         if pe_cfg is None:
             pe_cfg = self.default_pe_cfg()
         a = self.arrays
         sel = self._selects(config)
+        if "reg_mask" in pe_cfg:
+            zeros = jnp.zeros_like(pe_cfg["reg_mask"])
+            imm_mask, imm_val = _delayed_as_immediates(
+                pe_cfg["reg_mask"], pe_cfg.get("imm_mask", zeros),
+                pe_cfg.get("imm_val", zeros), state["pe_regs"])
+            pe_cfg = dict(pe_cfg, imm_mask=imm_mask, imm_val=imm_val)
         # value vector with zero sentinel at index N
         vals = jnp.zeros(a.num_nodes, dtype=jnp.int32)
         if len(a.reg_ids):
@@ -420,6 +461,8 @@ class FabricModule:
         vals = jax.lax.fori_loop(0, depth, body, vals)
         vals_ext = jnp.concatenate([vals, jnp.zeros(1, jnp.int32)])
         new_state = dict(state)
+        new_state["pe_regs"] = vals_ext[jnp.asarray(
+            self.fused_tables["pe_in"])]
         if len(a.reg_ids):
             new_state["regs"] = vals_ext[jnp.asarray(a.reg_src)]
         if self.num_mem:
@@ -466,11 +509,14 @@ class FabricModule:
                     "an explicit static max_depth") from e
         return jnp.asarray(depth, jnp.int32), int(max_depth)
 
-    def _norm_pe_cfg(self, pe_cfg: Dict[str, jnp.ndarray], b: int
+    def _norm_pe_cfg(self, pe_cfg: Dict[str, jnp.ndarray], b: int,
+                     pe_regs: Optional[jnp.ndarray] = None
                      ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
                                 jnp.ndarray]:
         """PE program tables shaped for the fused kernel: (B, P) op/const
-        and (B, P, 4) immediates, P = max(num_pe, 1) slots."""
+        and (B, P, 4) immediates, P = max(num_pe, 1) slots. Delayed ports
+        (``reg_mask``) become immediates holding ``pe_regs``, the (B, P,
+        4) values their ports carried last cycle."""
         p = self.fused_tables["num_pe_slots"]
         npe = self.num_pe
 
@@ -484,8 +530,11 @@ class FabricModule:
             x = jnp.asarray(pe_cfg[key], jnp.int32)[:, :npe]
             return jnp.pad(x, ((0, 0), (0, p - npe), (0, 0)))
 
-        return (pad2(pe_cfg["op"]), pad2(pe_cfg["const"]),
-                pad3("imm_mask"), pad3("imm_val"))
+        imm_mask, imm_val = pad3("imm_mask"), pad3("imm_val")
+        if pe_regs is not None and "reg_mask" in pe_cfg:
+            imm_mask, imm_val = _delayed_as_immediates(
+                pad3("reg_mask"), imm_mask, imm_val, pe_regs)
+        return pad2(pe_cfg["op"]), pad2(pe_cfg["const"]), imm_mask, imm_val
 
     def step_batch(self, state: Dict[str, jnp.ndarray], ext_in: jnp.ndarray,
                    config: jnp.ndarray,
@@ -497,8 +546,9 @@ class FabricModule:
         """One fabric clock cycle for B configurations at once.
 
         Every argument carries a leading batch dim: state regs (B, R) /
-        mem (B, M), ext_in (B, num_io), config (B, num_config), pe_cfg
-        leaves (B, ...). Returns (state', (B, num_io) observations).
+        mem (B, M) / pe_regs (B, P, 4), ext_in (B, num_io), config (B,
+        num_config), pe_cfg leaves (B, ...). Returns (state', (B, num_io)
+        observations).
 
         ``depth`` is either a shared int or a (B,) per-configuration sweep
         count: every lane runs the static ``max_depth`` loop but freezes
@@ -532,9 +582,10 @@ class FabricModule:
         # pinned sources on a zero background double as the initial values
         pin_vals = pin(jnp.zeros((b, a.num_nodes), dtype=jnp.int32))
 
+        t = self.fused_tables
+        op, const, imm_mask, imm_val = self._norm_pe_cfg(
+            pe_cfg, b, state["pe_regs"])
         if fused:
-            t = self.fused_tables
-            op, const, imm_mask, imm_val = self._norm_pe_cfg(pe_cfg, b)
             if self.use_pallas:
                 from repro.kernels import ops as kops
                 vals = kops.fabric_fused_batch(
@@ -552,12 +603,15 @@ class FabricModule:
                     jnp.asarray(t["pe_in"]), jnp.asarray(self.pe_out),
                     max_depth=max_depth, word=WORD)
         else:
+            lane_cfg = {"op": op, "const": const, "imm_mask": imm_mask,
+                        "imm_val": imm_val}
+
             def body(i, v):
                 v_ext = jnp.concatenate(
                     [v, jnp.zeros((b, 1), jnp.int32)], axis=1)
                 nv = self._sweep_batch(v_ext, sel)
                 nv = pin(nv)
-                nv = jax.vmap(self._eval_pes)(nv, pe_cfg)
+                nv = jax.vmap(self._eval_pes)(nv, lane_cfg)
                 return jnp.where((i < depths)[:, None], nv, v)
 
             vals = jax.lax.fori_loop(0, max_depth, body, pin_vals)
@@ -565,6 +619,7 @@ class FabricModule:
         vals_ext = jnp.concatenate(
             [vals, jnp.zeros((b, 1), jnp.int32)], axis=1)
         new_state = dict(state)
+        new_state["pe_regs"] = vals_ext[:, jnp.asarray(t["pe_in"])]
         if len(a.reg_ids):
             new_state["regs"] = vals_ext[:, jnp.asarray(a.reg_src)]
         if self.num_mem:
@@ -655,7 +710,9 @@ class FabricModule:
         stimulus is resident per grid step. Requires ``use_pallas`` and
         the fused engine; otherwise it is ignored (the reference scan
         already keeps the trace in host/HBM memory). Bit-identical to the
-        unstreamed path either way."""
+        unstreamed path either way; a PE program with delayed ports
+        (``reg_mask``) is refused there, since the streamed kernel
+        carries no PE input registers."""
         configs = jnp.asarray(configs)
         ext = jnp.asarray(ext_streams)
         b = configs.shape[0]
@@ -670,13 +727,15 @@ class FabricModule:
         max_depth = int(depths_np.max()) if b else 1
         if pe_cfgs is None:
             pe_cfgs = self.default_pe_cfg_batch(b)
+        if io_chunk and self.use_pallas and (fused is None or fused):
+            _refuse_delays_streamed(pe_cfgs)
         devices = jax.devices()
         n_dev = len(devices)
         use_shard = (n_dev > 1) if shard is None else shard
         if not use_shard or n_dev <= 1 or b == 0:
-            return self._run_batch_local(configs, ext, pe_cfgs,
-                                         jnp.asarray(depths_np),
-                                         max_depth, fused, io_chunk)
+            return self._run_batch_jit(configs, ext, pe_cfgs,
+                                       jnp.asarray(depths_np), max_depth,
+                                       fused, io_chunk)
 
         bp = -(-b // n_dev) * n_dev                     # ceil to devices
         pad = bp - b
@@ -685,22 +744,36 @@ class FabricModule:
             x = jnp.asarray(x)
             return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
 
-        mesh = Mesh(np.array(devices), ("b",))
+        out = self._sharded_program(tuple(devices))(
+            pad_b(configs), pad_b(ext),
+            {k: pad_b(v) for k, v in pe_cfgs.items()},
+            jnp.asarray(np.pad(depths_np, (0, pad))), max_depth, fused,
+            io_chunk)
+        return out[:b] if pad else out
+
+    def _sharded_program(self, devices: Tuple):
+        """``run_batch``'s program with the batch axis sharded across
+        ``devices`` (shard_map), jitted once per device tuple."""
+        program = self._sharded_programs.get(devices)
+        if program is not None:
+            return program
         spec = PartitionSpec("b")
 
-        def local(c, e, p, d):
-            return self._run_batch_local(c, e, p, d, max_depth, fused,
-                                         io_chunk)
+        def sharded(c, e, p, d, max_depth, fused, io_chunk):
+            def local(c, e, p, d):
+                return self._run_batch_local(c, e, p, d, max_depth, fused,
+                                             io_chunk)
 
-        # check_vma=False: shard_map has no varying-axes rule for
-        # pallas_call; every operand/output is explicitly batch-sharded
-        sharded = jax.shard_map(local, mesh=mesh,
-                                in_specs=(spec, spec, spec, spec),
-                                out_specs=spec, check_vma=False)
-        out = sharded(pad_b(configs), pad_b(ext),
-                      {k: pad_b(v) for k, v in pe_cfgs.items()},
-                      jnp.asarray(np.pad(depths_np, (0, pad))))
-        return out[:b] if pad else out
+            # check_vma=False: shard_map has no varying-axes rule for
+            # pallas_call; every operand/output is explicitly
+            # batch-sharded
+            return jax.shard_map(
+                local, mesh=Mesh(np.array(devices), ("b",)),
+                in_specs=(spec, spec, spec, spec), out_specs=spec,
+                check_vma=False)(c, e, p, d)
+
+        return self._sharded_programs.setdefault(
+            devices, jax.jit(sharded, static_argnums=(4, 5, 6)))
 
     # ------------------------------------------------- combinational depth
     def _selected_src_host(self, config: np.ndarray) -> np.ndarray:

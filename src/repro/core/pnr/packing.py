@@ -25,6 +25,16 @@ class PackedGraph:
     const_ports: Dict[str, Dict[str, int]] = field(default_factory=dict)
     #: host PE -> input ports that absorb one register delay
     reg_ports: Dict[str, List[str]] = field(default_factory=dict)
+    #: registers placed as ``pass`` PEs whose ``data0`` is delayed
+    placed_regs: List[str] = field(default_factory=list)
+
+    def delayed_ports(self) -> Dict[str, List[str]]:
+        """PE -> input ports that read last cycle's value: absorbed
+        registers and the ``data0`` of every placed register."""
+        out = {pe: list(ports) for pe, ports in self.reg_ports.items()}
+        for name in self.placed_regs:
+            out.setdefault(name, []).append("data0")
+        return out
 
 
 def pack(app: AppGraph) -> PackedGraph:
@@ -63,11 +73,10 @@ def pack(app: AppGraph) -> PackedGraph:
         if inst.kind in ("pe", "mem", "io_in", "io_out"):
             packed.placeable[name] = inst
         elif inst.kind == "reg":
-            # unpacked register: becomes an interconnect register demand;
-            # keep it placeable on a PE in pass mode (fallback)
-            inst.kind = "pe"
-            inst.op = "pass"
-            packed.placeable[name] = inst
+            # unpacked register: placed on a PE in pass mode whose data0
+            # input is delayed one cycle (the app graph keeps its reg)
+            packed.placeable[name] = AppInstance(name, "pe", op="pass")
+            packed.placed_regs.append(name)
 
     for net in app.nets:
         src, sport = net.src

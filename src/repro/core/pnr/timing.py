@@ -50,13 +50,22 @@ def _sink_arrivals(packed: PackedGraph, result: RoutingResult,
     relaxation :func:`sta_critical_path` gates on: entries are
     ``(net_name, sink_instance, sink_node_id, arrival_ns)`` where
     arrival is the combinational path delay into that sink (register
-    stages cut the path; split-FIFO control chains add back)."""
+    stages cut the path; split-FIFO control chains add back).
+
+    Application registers and memories are sequential, as the fabric
+    emulates them: a path ends at an absorbed register's PE input, at a
+    placed register's input and at a memory's ``wdata``, and their
+    outputs launch new paths after their core delay (``core_delay`` for
+    a register on a PE tile, 0.1 ns for a memory)."""
     res = result.resources
     # arrival time at each instance output = max over input nets of
-    # (arrival at net source + net comb delay) + core delay; registers in
-    # the app (packed into PEs) cut paths. Iterate in topological-ish order
-    # with relaxation (app graphs are small).
-    inst_arrival: Dict[str, float] = {}
+    # (arrival at net source + net comb delay) + core delay; sequential
+    # instances launch at their core delay and take nothing from their
+    # inputs. Iterate with relaxation (app graphs are small).
+    launch = {name: (0.1 if inst.kind == "mem" else core_delay)
+              for name, inst in packed.placeable.items()
+              if inst.kind == "mem" or name in packed.placed_regs}
+    inst_arrival: Dict[str, float] = dict(launch)
     net_by_name = {n.name: n for n in result.nets}
     app_nets = [n for n in packed.nets if n.name in net_by_name]
 
@@ -67,13 +76,19 @@ def _sink_arrivals(packed: PackedGraph, result: RoutingResult,
             rnet = net_by_name[net.name]
             src_arr = inst_arrival.get(net.src[0], 0.0)
             seg = _net_segment_delays(res, rnet.tree, rnet.src, rnet.sinks)
-            for (sink_inst, _), sink_id in zip(net.sinks, rnet.sinks):
+            for (sink_inst, sink_port), sink_id in zip(net.sinks,
+                                                       rnet.sinks):
                 d, regs = seg[sink_id]
                 ctrl = regs * split_fifo_ctrl_delay
                 arr_in = (src_arr if regs == 0 else 0.0) + d + ctrl
                 arrivals[(net.name, sink_inst, sink_id)] = arr_in
+                if sink_inst in launch:
+                    continue            # the path ends at a reg or mem
                 kind = packed.placeable.get(sink_inst)
                 cd = core_delay if (kind and kind.kind == "pe") else 0.1
+                # an absorbed register ends the path at its PE input
+                if sink_port in packed.reg_ports.get(sink_inst, ()):
+                    arr_in = 0.0
                 a = arr_in + cd
                 if a > inst_arrival.get(sink_inst, 0.0) + 1e-12:
                     inst_arrival[sink_inst] = a
@@ -89,7 +104,10 @@ def sta_critical_path(packed: PackedGraph, result: RoutingResult,
                       core_delay: float = 0.8,
                       split_fifo_ctrl_delay: float = 0.0
                       ) -> Dict[str, float]:
-    """Longest combinational path through routed nets + cores.
+    """Longest combinational path through routed nets + cores: the
+    latest arrival at any routed sink. A net's own source-to-sink delay,
+    which a route register or a sequential sink cuts, is reported apart
+    as ``max_net_delay_ns``.
 
     split_fifo_ctrl_delay models the paper's split-FIFO drawback: the FIFO
     control signals are not registered at tile boundaries, so chained
@@ -102,7 +120,7 @@ def sta_critical_path(packed: PackedGraph, result: RoutingResult,
     crit = max((arr for _, _, _, arr in arrivals), default=0.0)
     max_net = max((n.delay for n in result.nets), default=0.0)
     return {
-        "critical_path_ns": max(crit, max_net),
+        "critical_path_ns": crit,
         "max_net_delay_ns": max_net,
         "total_wirelength": float(result.total_wirelength()),
     }
@@ -130,8 +148,6 @@ def sta_net_slacks(packed: PackedGraph, result: RoutingResult,
     arrivals = _sink_arrivals(packed, result, core_delay,
                               split_fifo_ctrl_delay)
     crit = max((arr for _, _, _, arr in arrivals), default=0.0)
-    max_net = max((n.delay for n in result.nets), default=0.0)
-    crit = max(crit, max_net)
     period = float(clock_ns) if clock_ns is not None else crit
     rows = sorted(({"net": name, "sink": inst,
                     "arrival_ns": arr, "slack_ns": period - arr}

@@ -50,9 +50,12 @@ from typing import Dict, Iterator, List, Optional
 
 from .spec import InterconnectSpec
 
-#: bump when the envelope layout changes incompatibly; readers treat any
-#: other version as a miss rather than guessing
-SCHEMA_VERSION = 1
+#: bump when the envelope layout changes incompatibly or a record's
+#: numbers change meaning; readers treat any other version as a miss
+#: rather than guessing. 2: emulation delays app registers and
+#: memories (``out_checksum``), and STA cuts paths at them
+#: (``critical_path_ns``)
+SCHEMA_VERSION = 2
 
 #: env var naming the default store root (CI points it at a cached dir)
 STORE_ENV = "CANAL_RESULT_STORE"

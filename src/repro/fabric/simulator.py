@@ -14,6 +14,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.core import trace
 from repro.core.graph import Node
 from repro.core.lowering import FabricModule, PE_OP_IDS
 
@@ -26,7 +27,9 @@ class AppEmulator:
                  pe_ops: Dict[Tuple[int, int], Tuple[str, int]],
                  pe_imms: Optional[Dict[Tuple[int, int],
                                         Dict[int, int]]] = None,
-                 depth: Optional[int] = None):
+                 depth: Optional[int] = None,
+                 pe_delays: Optional[Dict[Tuple[int, int],
+                                          Sequence[int]]] = None):
         self.fabric = fabric
         self.config = jnp.asarray(fabric.route_to_config(route_edges))
         n = max(fabric.num_pe, 1)
@@ -34,6 +37,7 @@ class AppEmulator:
         consts = np.zeros(n, np.int32)
         imm_mask = np.zeros((n, 4), np.int32)
         imm_val = np.zeros((n, 4), np.int32)
+        reg_mask = np.zeros((n, 4), np.int32)
         coord_to_pe = {c: i for i, c in enumerate(fabric.pe_coords)}
         for coord, (op, const) in pe_ops.items():
             ops[coord_to_pe[coord]] = PE_OP_IDS[op]
@@ -42,9 +46,13 @@ class AppEmulator:
             for port_idx, val in ports.items():
                 imm_mask[coord_to_pe[coord], port_idx] = 1
                 imm_val[coord_to_pe[coord], port_idx] = val
+        for coord, ports in (pe_delays or {}).items():
+            for port_idx in ports:
+                reg_mask[coord_to_pe[coord], port_idx] = 1
         self.pe_cfg = {"op": jnp.asarray(ops), "const": jnp.asarray(consts),
                        "imm_mask": jnp.asarray(imm_mask),
-                       "imm_val": jnp.asarray(imm_val)}
+                       "imm_val": jnp.asarray(imm_val),
+                       "reg_mask": jnp.asarray(reg_mask)}
         self.io_index = {c: i for i, c in enumerate(fabric.io_coords)}
         # fixpoint sweeps: longest register-free chain of the routed tree
         # (replaces the conservative len(route_edges) + 4 bound)
@@ -54,9 +62,13 @@ class AppEmulator:
     @classmethod
     def from_pnr(cls, fabric: FabricModule, packed, result,
                  depth: Optional[int] = None) -> "AppEmulator":
-        """Bind a PnRResult directly (packing-aware)."""
+        """Bind a PnRResult directly (packing-aware): folded constants
+        become PE immediates, and app registers (absorbed into a PE input
+        or placed as a ``pass`` PE) delay their PE input one cycle."""
         pe_ops: Dict[Tuple[int, int], Tuple[str, int]] = {}
         pe_imms: Dict[Tuple[int, int], Dict[int, int]] = {}
+        pe_delays: Dict[Tuple[int, int], List[int]] = {}
+        delayed = packed.delayed_ports()
         for name, inst in packed.placeable.items():
             if inst.kind != "pe":
                 continue
@@ -64,8 +76,9 @@ class AppEmulator:
             pe_ops[xy] = (inst.op, inst.const)
             for port, val in packed.const_ports.get(name, {}).items():
                 pe_imms.setdefault(xy, {})[int(port[-1])] = val
+            pe_delays[xy] = [int(port[-1]) for port in delayed.get(name, ())]
         return cls(fabric, result.route_edges(), pe_ops, pe_imms,
-                   depth=depth)
+                   depth=depth, pe_delays=pe_delays)
 
     def ext_stream(self, inputs: Dict[Tuple[int, int], np.ndarray],
                    cycles: int) -> np.ndarray:
@@ -111,14 +124,19 @@ def run_apps_batch(emulators: Sequence[AppEmulator],
     fab = emulators[0].fabric
     if any(e.fabric is not fab for e in emulators):
         raise ValueError("batched emulation requires a shared fabric")
-    ext = np.stack([e.ext_stream(i, cycles)
-                    for e, i in zip(emulators, inputs_list)])   # (B, T, io)
-    configs = jnp.stack([e.config for e in emulators])
-    pe_cfgs = {k: jnp.stack([e.pe_cfg[k] for e in emulators])
-               for k in emulators[0].pe_cfg}
     depths = np.array([e.depth for e in emulators], dtype=np.int32)
-    obs = np.asarray(fab.run_batch(configs, jnp.asarray(ext),
-                                   pe_cfgs=pe_cfgs, depth=depths,
-                                   shard=shard, io_chunk=io_chunk))
-    return [{c: obs[b, :, i] for c, i in e.io_index.items()}
-            for b, e in enumerate(emulators)]
+    lanes = len(emulators)
+    with trace.span("emulate.run", lanes=lanes, cycles=cycles,
+                    app_cycles=lanes * cycles,
+                    sweeps=int(depths.max()) * cycles):
+        ext = np.stack([e.ext_stream(i, cycles)
+                        for e, i in zip(emulators, inputs_list)])
+        configs = jnp.stack([e.config for e in emulators])
+        pe_cfgs = {k: jnp.stack([e.pe_cfg[k] for e in emulators])
+                   for k in emulators[0].pe_cfg}
+        out = fab.run_batch(configs, jnp.asarray(ext), pe_cfgs=pe_cfgs,
+                            depth=depths, shard=shard, io_chunk=io_chunk)
+        with trace.span("device.wait"):
+            obs = np.asarray(out)
+        return [{c: obs[b, :, i] for c, i in e.io_index.items()}
+                for b, e in enumerate(emulators)]

@@ -227,6 +227,32 @@ def test_warm_sweep_recomputes_nothing(tmp_path):
             w["apps"]["pw"]["emulation"]["out_checksum"]
 
 
+def test_records_of_schema_1_are_recomputed(tmp_path):
+    """Schema 1 records emulated app registers and memories as wires and
+    timed paths through them: a store written then serves none of them;
+    the point is recomputed and stored again under the current schema."""
+    assert SCHEMA_VERSION == 2
+    store = ResultStore(str(tmp_path / "s"))
+    sweep_num_tracks((2,), width=4, height=4, executor=_executor(store))
+    records = os.path.join(store.root, "records")
+    paths = [os.path.join(records, f) for f in os.listdir(records)
+             if f.endswith(".json")]
+    assert paths
+    for path in paths:
+        with open(path) as f:
+            env = json.load(f)
+        with open(path, "w") as f:
+            json.dump(dict(env, schema=1), f)
+
+    old = ResultStore(str(tmp_path / "s"))
+    ex = _executor(old)
+    sweep_num_tracks((2,), width=4, height=4, executor=ex)
+    assert ex.store_hits == 0 and ex.pnr_computations == 1
+    for path in paths:
+        with open(path) as f:
+            assert json.load(f)["schema"] == SCHEMA_VERSION
+
+
 def test_store_mismatched_context_is_a_miss(tmp_path):
     """A record computed without emulation (or for different apps) must
     not satisfy an executor that needs more — it is recomputed."""
