@@ -13,12 +13,14 @@ Because the structural graph contains *potential* combinational cycles
 (register-bypass muxes), the fabric evaluates each cycle by fixpoint
 sweeps: one sweep propagates every node's value one combinational level.
 A legal configuration's active network is acyclic, so ``depth`` sweeps
-(≥ longest configured combinational path) reach the fixed point. The sweep
-itself is the perf hot spot and has a Pallas kernel
-(``repro.kernels.fabric_step``); the batched path runs the whole fixpoint
-— PE cores included — as one fused kernel call per cycle, masks each
-configuration to its own combinational depth, and shards the batch axis
-across devices (``run_batch``).
+(≥ longest configured combinational path) reach the fixed point. The
+batched path runs the whole fixpoint — PE cores included — as one engine
+call per cycle, masks each configuration to its own combinational depth,
+and shards the batch axis across devices (``run_batch``). Its served
+engine (``repro.kernels.fabric_chain``) collapses every chain of selected
+inputs to its root once a batch, so a sweep evaluates only the PE input
+slots; the N-wide sweep has Pallas kernels (``repro.kernels.fabric_step``)
+and an oracle (``repro.kernels.ref``).
 """
 from __future__ import annotations
 
@@ -31,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
+from repro.kernels import fabric_chain
 from repro.kernels.fabric_step import PE_OPS, pe_alu_candidates
 
 from .graph import IO, Interconnect, Node, NodeKind
@@ -267,8 +270,14 @@ class FabricModule:
             "pe_in": pe_in,
             "pe_res_idx": pe_res_idx,
             "num_pe_slots": p,
+            # the nodes a cycle reads out: the PE inputs (their delay
+            # registers), the register sources, the memory inputs and the
+            # observed IO ports
+            "read": np.concatenate([pe_in.ravel(), a.reg_src, self.mem_in,
+                                    self.io_out_nodes]).astype(np.int32),
         }
         self._stream_tables: Optional[Dict[str, np.ndarray]] = None
+        self._chain_consts: Optional[Dict[str, np.ndarray]] = None
 
     def stream_tables(self) -> Dict[str, np.ndarray]:
         """Node tables for the streamed fused engine: the node → state
@@ -297,10 +306,44 @@ class FabricModule:
             }
         return self._stream_tables
 
+    def chain_consts(self) -> Dict[str, np.ndarray]:
+        """Node tables for the chain engine (``kernels/fabric_chain.py``),
+        each (N + 1,) with the sentinel: ``root`` (held, pinned and PE
+        output nodes), ``res`` (``pe_res_idx``) and ``pin_src`` (the
+        streamed engine's node -> pinned-state map)."""
+        if self._chain_consts is None:
+            a, t = self.arrays, self.fused_tables
+            n, p = a.num_nodes, t["num_pe_slots"]
+            pin_src = self.stream_tables()["pin_src"]
+            outs = self.pe_out.ravel()
+            if np.any(a.is_driven[outs]) or np.any(t["pin_mask"][outs]):
+                raise NotImplementedError(
+                    "the chain engine needs every PE output node undriven "
+                    "and unpinned")
+            res = np.append(t["pe_res_idx"], 2 * p).astype(np.int32)
+            s_zero = len(a.reg_ids) + self.num_io + self.num_mem
+            self._chain_consts = {
+                "root": np.append(
+                    (t["keep"] | t["pin_mask"]) | (res[:n] < 2 * p),
+                    1).astype(np.int32),
+                "res": res,
+                "pin_src": np.append(pin_src, s_zero).astype(np.int32),
+            }
+        return self._chain_consts
+
     # -------------------------------------------------------------- interface
     @property
     def num_config(self) -> int:
         return self.arrays.num_config
+
+    @property
+    def slots_per_sweep(self) -> int:
+        """Node values the fused engine gathers per lane per sweep: the
+        4P PE input slots on the chain engine (XLA), every node on the
+        Pallas sweep engines."""
+        if self.use_pallas:
+            return self.arrays.num_nodes
+        return 4 * self.fused_tables["num_pe_slots"]
 
     def init_state(self) -> Dict[str, jnp.ndarray]:
         """Registers, memories and the PE input registers (``pe_regs``,
@@ -541,7 +584,8 @@ class FabricModule:
                    pe_cfg: Optional[Dict[str, jnp.ndarray]] = None,
                    depth: DepthSpec = 16,
                    max_depth: Optional[int] = None,
-                   fused: Optional[bool] = None
+                   fused: Optional[bool] = None,
+                   chains: Optional[Dict[str, jnp.ndarray]] = None
                    ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
         """One fabric clock cycle for B configurations at once.
 
@@ -554,11 +598,16 @@ class FabricModule:
         count: every lane runs the static ``max_depth`` loop but freezes
         once its own count is reached, so each configuration performs
         exactly its own fixpoint. ``fused`` (default True) runs the whole
-        fixpoint — PE evaluation included — as one fused kernel call
-        (``fabric_fused_batch`` when ``use_pallas``, its vmapped pure-jnp
-        oracle otherwise); ``fused=False`` keeps the sweep-at-a-time loop
-        (per-sweep batched Pallas gather + Python-level PE evaluation),
-        bit-identical, as the unfused baseline."""
+        fixpoint — PE evaluation included — as one engine call: on XLA
+        (``use_pallas=False``, the served engine) the chain engine of
+        ``kernels/fabric_chain.py``, whose sweeps evaluate only the 4P PE
+        input slots; with ``use_pallas`` the ``fabric_fused_batch``
+        kernel. ``ref.fabric_fused_batch_ref``, the N-wide sweep oracle,
+        is their test reference. ``fused=False`` keeps the
+        sweep-at-a-time loop (per-sweep batched gather + Python-level PE
+        evaluation), bit-identical, as the unfused baseline. ``chains``
+        are the chain engine's per-batch tables (``chain_tables``), built
+        here when not given."""
         b = config.shape[0]
         if pe_cfg is None:
             pe_cfg = self.default_pe_cfg_batch(b)
@@ -566,6 +615,53 @@ class FabricModule:
             fused = True
         a = self.arrays
         depths, max_depth = self._norm_depth(depth, max_depth, b)
+        op, const, imm_mask, imm_val = self._norm_pe_cfg(
+            pe_cfg, b, state["pe_regs"])
+        if fused and not self.use_pallas:
+            if chains is None:
+                chains = self.chain_tables(config, max_depth)
+            pin_state = jnp.concatenate(
+                [state["regs"], ext_in.astype(jnp.int32),
+                 state["mem"][:, :self.num_mem],
+                 jnp.zeros((b, 1), jnp.int32)], axis=1)
+            reads = fabric_chain.chain_fixpoint(
+                chains, pin_state, depths, op, const, imm_mask, imm_val,
+                max_depth=max_depth, word=WORD)
+        else:
+            reads = self._sweep_fixpoint(state, ext_in, config, depths,
+                                         max_depth, fused, op, const,
+                                         imm_mask, imm_val)
+        p = self.fused_tables["num_pe_slots"]
+        n_reg = len(a.reg_ids)
+        new_state = dict(state)
+        new_state["pe_regs"] = reads[:, :4 * p].reshape(b, p, 4)
+        reads = reads[:, 4 * p:]
+        if n_reg:
+            new_state["regs"] = reads[:, :n_reg]
+        if self.num_mem:
+            new_state["mem"] = state["mem"].at[:, :self.num_mem].set(
+                reads[:, n_reg:n_reg + self.num_mem])
+        return new_state, reads[:, n_reg + self.num_mem:]
+
+    def chain_tables(self, configs: jnp.ndarray,
+                     max_depth: int) -> Dict[str, jnp.ndarray]:
+        """The chain engine's per-batch tables for (B, num_config)
+        ``configs`` and a static sweep bound: built once a batch, since
+        the selects do not change from cycle to cycle."""
+        c = self.chain_consts()
+        return fabric_chain.chain_tables(
+            jax.vmap(self._selects)(configs), jnp.asarray(self.arrays.src),
+            jnp.asarray(c["root"]), jnp.asarray(c["res"]),
+            jnp.asarray(c["pin_src"]),
+            jnp.asarray(self.fused_tables["read"]), max_depth)
+
+    def _sweep_fixpoint(self, state, ext_in, config, depths, max_depth,
+                        fused, op, const, imm_mask, imm_val) -> jnp.ndarray:
+        """The N-wide sweep engines of ``step_batch`` (Pallas fused, or
+        unfused): the fixpoint's values at the read-out nodes
+        (``fused_tables["read"]``), (B, K)."""
+        a = self.arrays
+        b = config.shape[0]
         sel = jax.vmap(self._selects)(config)          # (B, N)
 
         def pin(v):
@@ -583,25 +679,14 @@ class FabricModule:
         pin_vals = pin(jnp.zeros((b, a.num_nodes), dtype=jnp.int32))
 
         t = self.fused_tables
-        op, const, imm_mask, imm_val = self._norm_pe_cfg(
-            pe_cfg, b, state["pe_regs"])
         if fused:
-            if self.use_pallas:
-                from repro.kernels import ops as kops
-                vals = kops.fabric_fused_batch(
-                    pin_vals, sel, pin_vals, depths, op, const, imm_mask,
-                    imm_val, jnp.asarray(a.src),
-                    jnp.asarray(t["keep"]), jnp.asarray(t["pin_mask"]),
-                    jnp.asarray(t["pe_in"]), jnp.asarray(t["pe_res_idx"]),
-                    max_depth=max_depth, word=WORD)
-            else:
-                from repro.kernels import ref as kref
-                vals = kref.fabric_fused_batch_ref(
-                    pin_vals, sel, pin_vals, depths, op, const, imm_mask,
-                    imm_val, jnp.asarray(a.src),
-                    jnp.asarray(t["keep"]), jnp.asarray(t["pin_mask"]),
-                    jnp.asarray(t["pe_in"]), jnp.asarray(self.pe_out),
-                    max_depth=max_depth, word=WORD)
+            from repro.kernels import ops as kops
+            vals = kops.fabric_fused_batch(
+                pin_vals, sel, pin_vals, depths, op, const, imm_mask,
+                imm_val, jnp.asarray(a.src),
+                jnp.asarray(t["keep"]), jnp.asarray(t["pin_mask"]),
+                jnp.asarray(t["pe_in"]), jnp.asarray(t["pe_res_idx"]),
+                max_depth=max_depth, word=WORD)
         else:
             lane_cfg = {"op": op, "const": const, "imm_mask": imm_mask,
                         "imm_val": imm_val}
@@ -618,16 +703,7 @@ class FabricModule:
 
         vals_ext = jnp.concatenate(
             [vals, jnp.zeros((b, 1), jnp.int32)], axis=1)
-        new_state = dict(state)
-        new_state["pe_regs"] = vals_ext[:, jnp.asarray(t["pe_in"])]
-        if len(a.reg_ids):
-            new_state["regs"] = vals_ext[:, jnp.asarray(a.reg_src)]
-        if self.num_mem:
-            new_state["mem"] = state["mem"].at[:, :self.num_mem].set(
-                vals_ext[:, jnp.asarray(self.mem_in)])
-        io_obs = (vals_ext[:, jnp.asarray(self.io_out_nodes)]
-                  if self.num_io else jnp.zeros((b, 0), jnp.int32))
-        return new_state, io_obs
+        return vals_ext[:, jnp.asarray(t["read"])]
 
     def _run_batch_stream(self, configs: jnp.ndarray, ext: jnp.ndarray,
                           pe_cfgs: Dict[str, jnp.ndarray],
@@ -670,11 +746,13 @@ class FabricModule:
         b = configs.shape[0]
         state = self.init_state_batch(b)
         xs = jnp.swapaxes(ext, 0, 1)                    # (T, B, io)
+        chains = (self.chain_tables(configs, max_depth)
+                  if fused is not False and not self.use_pallas else None)
 
         def scan_fn(st, x):
             st, obs = self.step_batch(st, x, configs, pe_cfgs,
                                       depth=depths, max_depth=max_depth,
-                                      fused=fused)
+                                      fused=fused, chains=chains)
             return st, obs
 
         _, out = jax.lax.scan(scan_fn, state, xs)
@@ -700,8 +778,10 @@ class FabricModule:
         ``shard`` (default: auto, on when >1 device) splits the batch axis
         across ``jax.devices()`` via shard_map, padding B up to a multiple
         of the device count; on a single device the local path runs
-        unsharded. ``fused`` selects the fused kernel engine (default) or
-        the sweep-at-a-time baseline.
+        unsharded. ``fused`` selects the fused engine (default: the chain
+        engine on XLA, whose tables are built once a batch, before the
+        scan; the Pallas kernel with ``use_pallas``) or the
+        sweep-at-a-time baseline.
 
         ``io_chunk`` streams the external IO from HBM in chunks of that
         many cycles through the fused multi-cycle kernel

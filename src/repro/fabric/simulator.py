@@ -107,8 +107,11 @@ def run_apps_batch(emulators: Sequence[AppEmulator],
                    ) -> List[Dict[Tuple[int, int], np.ndarray]]:
     """Emulate several routed applications on the *same* fabric as one
     batch: all configs/PE programs/IO streams advance together through a
-    single ``FabricModule.run_batch`` scan (the fused batched Pallas
-    kernel when the fabric was compiled with ``use_pallas=True``).
+    single ``FabricModule.run_batch`` scan. The engine is the chain
+    engine (``kernels/fabric_chain.py``), whose sweeps evaluate only the
+    4P PE input slots; the fused batched Pallas kernel when the fabric
+    was compiled with ``use_pallas=True``. Both are tested against the
+    N-wide sweep oracle, ``kernels/ref.py`` ``fabric_fused_batch_ref``.
 
     Each app sweeps exactly its own routed combinational depth — lanes
     with shallower routes freeze early instead of padding to the batch
@@ -118,7 +121,11 @@ def run_apps_batch(emulators: Sequence[AppEmulator],
     axis is split across devices when more than one is visible.
     ``io_chunk`` forwards too: on the Pallas fused engine, long stimulus
     traces stream from HBM in chunks of that many cycles instead of
-    materializing (B, T, io) next to the value matrices."""
+    materializing (B, T, io) next to the value matrices.
+
+    The ``emulate.run`` span records ``sweeps`` (the static sweep bound
+    times the cycles) and ``slots_per_sweep``, the node values the
+    engine gathers per lane per sweep."""
     if not emulators:
         return []
     fab = emulators[0].fabric
@@ -128,7 +135,8 @@ def run_apps_batch(emulators: Sequence[AppEmulator],
     lanes = len(emulators)
     with trace.span("emulate.run", lanes=lanes, cycles=cycles,
                     app_cycles=lanes * cycles,
-                    sweeps=int(depths.max()) * cycles):
+                    sweeps=int(depths.max()) * cycles,
+                    slots_per_sweep=fab.slots_per_sweep):
         ext = np.stack([e.ext_stream(i, cycles)
                         for e, i in zip(emulators, inputs_list)])
         configs = jnp.stack([e.config for e in emulators])

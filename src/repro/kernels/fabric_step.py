@@ -59,8 +59,10 @@ Validated in interpret mode against ``ref.fabric_sweep_ref`` /
 Mosaic refuses the 1-D ``jnp.take`` of the sweep kernels
 (``_gather_lowering_rule``) and the fused kernels' gathers ("Only 2D
 gather is supported"). They run in interpret mode only, and
-``FabricModule(use_pallas=True)`` refuses a TPU backend; the served
-engine is the XLA one, ``ref.fabric_fused_batch_ref``.
+``FabricModule(use_pallas=True)`` refuses a TPU backend. The served
+engine is the XLA chain engine, ``fabric_chain.chain_fixpoint``, whose
+sweeps evaluate only the PE input slots; ``ref.fabric_fused_batch_ref``,
+the N-wide sweep oracle, is its test reference and these kernels'.
 """
 from __future__ import annotations
 

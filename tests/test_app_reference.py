@@ -265,7 +265,8 @@ def test_route_registers_delay_the_connection():
 def test_emulate_routed_takes_stimulus_and_records_spans():
     """``SweepExecutor.emulate_routed`` drives a given stimulus (the
     counter stays the default) and records ``emulate.bind``,
-    ``emulate.run`` with its sweep count, and ``device.wait``."""
+    ``emulate.run`` with its sweep count and the slots the chain engine
+    gathers a sweep (4P), and ``device.wait``."""
     f = routed("8x8_mem4")
     names = ("fir", "stencil")
     routed_apps = [(n, f.pnr(n).packed, f.pnr(n)) for n in names]
@@ -288,5 +289,6 @@ def test_emulate_routed_takes_stimulus_and_records_spans():
     run = spans["emulate.run"].attrs
     depths = [got[n][0] for n in names]
     assert run == {"lanes": 2, "cycles": T, "app_cycles": 2 * T,
-                   "sweeps": max(depths) * T}
+                   "sweeps": max(depths) * T,
+                   "slots_per_sweep": 4 * f.fab.num_pe}
     assert spans["device.wait"].parent == spans["emulate.run"].id

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import hpwl, minplus, ref
+from repro.kernels import fabric_chain, hpwl, minplus, ref
 
 FULL_NODES = 86_288     # cgra_amber.FULL lowered
 FULL_FANIN = 20
@@ -35,6 +35,8 @@ FULL_TILES = 32 * 32    # the min-plus router's coarse graph
 AMBER_NETS = 1024       # ~one net per placed instance at FULL
 NET_PINS = 16
 HBM_BYTES = 16 * 10**9  # v5e HBM per chip
+#: ``canal_amber_full`` lowered: nodes, PEs, registers, memories, IOs
+AMBER = dict(n=51_192, p=384, r=11_720, m=128, io=100)
 
 
 @pytest.fixture(scope="module")
@@ -97,9 +99,9 @@ def test_net_box_kernels_compile(one_chip, kernel):
 
 
 def test_fused_xla_emulation_step_compiles(one_chip):
-    """The served emulation step (``use_pallas=False``): the fused
-    fixpoint of ``ref.fabric_fused_batch_ref`` for 8 configurations at
-    FULL, depth 16, fits one chip."""
+    """The sweep oracle of the emulation step, the served chain engine's
+    test reference: the fused fixpoint of ``ref.fabric_fused_batch_ref``
+    for 8 configurations at FULL, depth 16, fits one chip."""
     b, n, f, p = 8, FULL_NODES, FULL_FANIN, FULL_PES
 
     def i32(*shape):
@@ -110,6 +112,37 @@ def test_fused_xla_emulation_step_compiles(one_chip):
         i32(b, n), i32(b, n), i32(b, n), i32(b), i32(b, p), i32(b, p),
         i32(b, p, 4), i32(b, p, 4), i32(n, f), i32(n), i32(n), i32(p, 4),
         i32(p, 2))
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert 0 < used < HBM_BYTES
+
+
+def test_chain_emulation_step_compiles(one_chip):
+    """The served emulation step (``use_pallas=False``): the chain
+    engine's per-batch tables and one cycle's fixpoint for the
+    benchmark's batch on Amber's array (B = 5, depth 170) fit one
+    chip."""
+    b, depth, f = 5, 170, FULL_FANIN
+    n, p = AMBER["n"], AMBER["p"]
+    k = 4 * p + AMBER["r"] + AMBER["m"] + AMBER["io"]
+    s = AMBER["r"] + AMBER["io"] + AMBER["m"] + 1
+
+    def i32(*shape):
+        return _shape(one_chip, shape, jnp.int32)
+
+    def cycle(sel, src, root, res, pin_src, read, pin_state, depths, op,
+              const, imm_mask, imm_val):
+        chains = fabric_chain.chain_tables(sel, src, root, res, pin_src,
+                                           read, depth)
+        return fabric_chain.chain_fixpoint(
+            chains, pin_state, depths, op, const, imm_mask, imm_val,
+            max_depth=depth)
+
+    compiled = _compile(
+        cycle, i32(b, n), i32(n, f), i32(n + 1), i32(n + 1), i32(n + 1),
+        i32(k), i32(b, s), i32(b), i32(b, p), i32(b, p), i32(b, p, 4),
+        i32(b, p, 4))
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)

@@ -218,6 +218,25 @@ def test_minplus_scope_in_lowered_text():
     assert "canal.minplus" in _lowered(minplus._ref_block, d, w, iters=2)
 
 
+def test_emulate_run_records_slots_per_sweep():
+    """``emulate.run`` says how much the engine gathers a sweep: the 4P
+    PE input slots on the chain engine, beside the sweeps."""
+    from repro.core.lowering import compile_interconnect
+    from repro.core.passes import PassManager
+    from repro.fabric import AppEmulator, run_apps_batch
+
+    fab = compile_interconnect(
+        PassManager().run(InterconnectSpec(width=4, height=4, num_tracks=2)))
+    assert fab.num_pe > 1
+    emus = [AppEmulator(fab, [], {}, depth=d) for d in (2, 3)]
+    with trace.recording() as rec:
+        run_apps_batch(emus, [{}, {}], cycles=4, shard=False)
+    run = next(s for s in rec.spans if s.name == "emulate.run")
+    assert run.attrs["slots_per_sweep"] == 4 * fab.num_pe
+    assert run.attrs["slots_per_sweep"] < fab.arrays.num_nodes
+    assert run.attrs["sweeps"] == 3 * 4
+
+
 def test_emulate_scope_in_lowered_text():
     from repro.core.lowering import compile_interconnect
     from repro.core.passes import PassManager
