@@ -1,11 +1,8 @@
 """Abstract claim — "Canal enables fast design space exploration": IR
-generation + hardware lowering speed vs array size, plus the batched DSE
-engine: B fabric configurations emulated as one ``run_batch`` scan vs the
-serial per-config baseline, the fused engine (whole fixpoint + PE eval
-per cycle) vs the sweep-at-a-time PR-1 path, batch-axis sharding across
-the devices of this process, and the
-spec-addressed persistent result store: the same track sweep cold
-(computing + persisting) vs warm (served from the store, zero PnR).
+generation + hardware lowering speed vs array size, the spec-addressed
+persistent result store (the same track sweep cold, computing and
+persisting, vs warm, served from the store with zero PnR) and
+search-driven DSE vs the exhaustive grid. Host wall-clocks.
 Results go to ``benchmarks/results/dse_speed.json``."""
 from __future__ import annotations
 
@@ -13,11 +10,7 @@ import os
 import time
 from typing import Dict
 
-import jax
-
-from repro.core.dse import (batched_vs_serial_emulation,
-                            fused_vs_unfused_emulation, generation_speed,
-                            sharded_vs_single_emulation)
+from repro.core.dse import generation_speed
 
 from .common import emit, save_json, timed
 
@@ -146,58 +139,6 @@ def run(quick: bool = False):
             f"nodes={r['nodes']} gen={r['gen_seconds'] * 1e3:.0f}ms "
             f"lower={r['lower_seconds'] * 1e3:.0f}ms"))
 
-    # batched configuration emulation: the production run_batch path
-    # (the fused XLA engine) vs looping run per config
-    batch = 4 if quick else 8
-    cycles = 8 if quick else 16
-    width = 4 if quick else 6
-    tracks = 2 if quick else 4
-    emu = batched_vs_serial_emulation(width=width, height=width,
-                                      num_tracks=tracks,
-                                      batch=batch, cycles=cycles)
-    lines.append(emit(
-        f"dse_speed/batched_emulation_b={emu['batch']}",
-        emu["batched_seconds"] * 1e6,
-        f"serial={emu['serial_seconds'] * 1e3:.0f}ms "
-        f"batched={emu['batched_seconds'] * 1e3:.0f}ms "
-        f"speedup={emu['speedup']:.2f}x depth={emu['depth']}"))
-    # both paths are pre-warmed; the measured margin is ~2.5-4x, so a 1.5x
-    # tolerance only absorbs shared-runner timing noise, not a regression
-    assert emu["batched_seconds"] <= emu["serial_seconds"] * 1.5, \
-        "batched DSE emulation must not be slower than the serial baseline"
-
-    # fused engine (one fused step per cycle, PE cores inside it,
-    # per-config depth masking) vs the sweep-at-a-time PR-1 baseline
-    fus = fused_vs_unfused_emulation(width=width, height=width,
-                                     num_tracks=tracks, batch=batch,
-                                     cycles=cycles)
-    lines.append(emit(
-        f"dse_speed/fused_emulation_b={fus['batch']}",
-        fus["fused_seconds"] * 1e6,
-        f"unfused={fus['unfused_seconds'] * 1e3:.0f}ms "
-        f"fused={fus['fused_seconds'] * 1e3:.0f}ms "
-        f"speedup={fus['speedup']:.2f}x "
-        f"depths={fus['min_depth']}..{fus['max_depth']}"))
-    # measured margin ~1.3x in favour of the fused engine; the tolerance
-    # absorbs runner noise while still catching a real regression
-    assert fus["fused_seconds"] <= fus["unfused_seconds"] * 1.2, \
-        "fused DSE engine must not regress the sweep-at-a-time baseline"
-
-    # batch-axis sharding across this process's devices (1 device ->
-    # fallback parity check)
-    shd = sharded_vs_single_emulation(width=4, height=4, num_tracks=2,
-                                      batch=batch, cycles=cycles)
-    lines.append(emit(
-        f"dse_speed/sharded_emulation_dev={shd['devices']}",
-        shd["sharded_seconds"] * 1e6,
-        f"single={shd['single_seconds'] * 1e3:.0f}ms "
-        f"sharded={shd['sharded_seconds'] * 1e3:.0f}ms "
-        f"speedup={shd['speedup']:.2f}x"))
-    if len(jax.devices()) == 1:
-        # same code path either way; anything beyond noise is a bug in
-        # the single-device fallback
-        assert shd["sharded_seconds"] <= shd["single_seconds"] * 1.5, \
-            "single-device shard fallback must not add overhead"
     # persistent result store: cold (compute + persist) vs warm (served
     # by digest, zero PnR asserted inside)
     wc = store_warm_vs_cold(quick=quick)
@@ -219,9 +160,7 @@ def run(quick: bool = False):
         f"evals={sg['search_evaluations']}/{sg['grid_size']} "
         f"best_tracks={sg['best_num_tracks']}"))
 
-    save_json("dse_speed", {"generation": recs, "batched_emulation": emu,
-                            "fused_emulation": fus,
-                            "sharded_emulation": shd,
+    save_json("dse_speed", {"generation": recs,
                             "store_warm_vs_cold": wc,
                             "search_vs_grid": sg})
     return lines

@@ -18,17 +18,6 @@ def run(quick: bool = False):
     rng = np.random.default_rng(0)
     lines = []
 
-    n, f = 2048, 6
-    vals = jnp.asarray(rng.integers(0, 999, n + 1).astype(np.int32))
-    src = jnp.asarray(rng.integers(0, n + 1, (n, f)).astype(np.int32))
-    sel = jnp.asarray(rng.integers(0, f, n).astype(np.int32))
-    out, us = timed(lambda: ops.fabric_sweep(vals, src, sel)
-                    .block_until_ready())
-    err = int(np.abs(np.asarray(out)
-                     - np.asarray(ref.fabric_sweep_ref(vals, src,
-                                                       sel))).max())
-    lines.append(emit("kernel/fabric_sweep", us, f"maxerr={err}"))
-
     pins = jnp.asarray(rng.integers(0, 64, (1024, 8, 2)).astype(np.int32))
     mask = jnp.asarray((rng.random((1024, 8)) < 0.8).astype(np.int32))
     out, us = timed(lambda: ops.hpwl(pins, mask).block_until_ready())

@@ -72,10 +72,9 @@ def serve_phase(label: str) -> None:
         served_emulation = ex.emulate_routed
         emulated = []
 
-        def emulate_and_keep(fab, routed, device=None, io_chunk=None):
+        def emulate_and_keep(fab, routed, device=None):
             t0 = time.perf_counter()
-            outs = served_emulation(fab, routed, device=device,
-                                    io_chunk=io_chunk)
+            outs = served_emulation(fab, routed, device=device)
             emulated.append((fab, routed, outs, time.perf_counter() - t0))
             return outs
 
@@ -167,7 +166,7 @@ def sharded_phase(label: str, seed: int = 0) -> None:
 
     import canal
     from repro.configs import cgra_amber
-    from repro.kernels.fabric_step import PE_OPS
+    from repro.kernels.fabric_chain import PE_OPS
 
     t0 = time.perf_counter()
     fab = canal.compile(cgra_amber.FULL, analyze="off").fabric()

@@ -21,7 +21,7 @@ def smoke() -> InterconnectSpec:
                             io_ring=True)
 
 
-def compiled_smoke(use_pallas: bool = False):
+def compiled_smoke():
     """The smoke design point through the compile front door."""
     from repro.core.compile import compile_spec
-    return compile_spec(smoke(), use_pallas=use_pallas)
+    return compile_spec(smoke())

@@ -30,12 +30,11 @@ class CompiledFabric:
 
     def __init__(self, spec: InterconnectSpec, ic: Interconnect,
                  pass_log: Optional[List[Dict]] = None,
-                 use_pallas: bool = False, cacheable: bool = True,
+                 cacheable: bool = True,
                  diagnostics=None):
         self.spec = spec
         self._ic = ic
         self.pass_log = list(pass_log or [])
-        self.use_pallas = use_pallas
         #: False when a custom (non-serializable) core_fn was injected:
         #: the spec digest then under-describes the design, so
         #: digest-keyed caches must not admit this fabric
@@ -43,7 +42,7 @@ class CompiledFabric:
         #: the static-analysis AnalysisReport produced at compile time
         #: (None when compiled with analyze="off" or constructed raw)
         self.diagnostics = diagnostics
-        self._fabrics: Dict[Tuple[bool, bool], object] = {}
+        self._fabric = None
         self._resources: Dict[float, object] = {}
         self._codec = None
 
@@ -68,26 +67,21 @@ class CompiledFabric:
                 f"digest={self.digest()[:12]})")
 
     # ------------------------------------------------------------- backends
-    def fabric(self, use_pallas: Optional[bool] = None):
+    def fabric(self):
         """The lowered functional model: :class:`FabricModule` for the
         static interconnect, :class:`repro.fabric.RVFabric` when the spec
-        requests the hybrid ready-valid interconnect. Memoized per
-        (engine, rv) pair."""
-        up = self.use_pallas if use_pallas is None else use_pallas
-        key = (up, self.spec.ready_valid)
-        fab = self._fabrics.get(key)
-        if fab is None:
+        requests the hybrid ready-valid interconnect. Memoized."""
+        if self._fabric is None:
             if self.spec.ready_valid:
                 from repro.fabric import RVFabric
                 # the readyvalid_transform pass annotated the IR; the
                 # lowering consumes that annotation, not the raw spec
                 mode = self._ic.params["rv_fifo_mode"]
-                fab = RVFabric(self._ic, fifo_mode=mode, use_pallas=up)
+                self._fabric = RVFabric(self._ic, fifo_mode=mode)
             else:
                 from .lowering import FabricModule
-                fab = FabricModule(self._ic, use_pallas=up)
-            self._fabrics[key] = fab
-        return fab
+                self._fabric = FabricModule(self._ic)
+        return self._fabric
 
     def resources(self, reg_penalty: float = 4.0):
         """Shared :class:`RoutingResources` (adjacency, base costs,
@@ -120,8 +114,7 @@ class CompiledFabric:
                          severities=severities, fail_on=fail_on)
 
     def verify(self, rules: Optional[Sequence[str]] = None,
-               fail_on: Optional[str] = "error",
-               use_pallas: Optional[bool] = None):
+               fail_on: Optional[str] = "error"):
         """Run the post-lowering verification analyses (the paper's §3.3
         checks, registered as ``scope="lowered"`` rules:
         ``structural-equivalence`` and the exhaustive ``config-sweep``)
@@ -135,7 +128,7 @@ class CompiledFabric:
                 "lowered verification covers the static interconnect; "
                 "the ready-valid fabric has its own emulation tests")
         return run_rules(self._ic, spec=self.spec, rules=rules,
-                         scope="lowered", fabric=self.fabric(use_pallas),
+                         scope="lowered", fabric=self.fabric(),
                          fail_on=fail_on)
 
     # ------------------------------------------------------------------ PnR
@@ -188,9 +181,7 @@ class CompiledFabric:
 
     # ------------------------------------------------------------ emulation
     def emulate(self, result, inputs: Dict[Union[str, Coord], np.ndarray],
-                cycles: int,
-                use_pallas: Optional[bool] = None) -> Dict[Coord,
-                                                           np.ndarray]:
+                cycles: int) -> Dict[Coord, np.ndarray]:
         """Emulate a routed application for ``cycles`` fabric clocks.
 
         ``result`` is the :class:`PnRResult` from
@@ -201,7 +192,7 @@ class CompiledFabric:
 
         if not result.success:
             raise ValueError(f"cannot emulate failed PnR: {result.error}")
-        fab = self.fabric(use_pallas)
+        fab = self.fabric()
         emu = AppEmulator.from_pnr(fab, result.packed, result)
         ins: Dict[Coord, np.ndarray] = {}
         for k, v in inputs.items():
@@ -240,7 +231,6 @@ class CompiledFabric:
 
 
 def compile_spec(spec: InterconnectSpec, core_fn=None,
-                 use_pallas: bool = False,
                  passes=None,
                  analyze: str = "warn",
                  analyze_per_pass: bool = False) -> CompiledFabric:
@@ -255,5 +245,5 @@ def compile_spec(spec: InterconnectSpec, core_fn=None,
     that introduced it."""
     from .passes import DEFAULT_PASSES, PassManager
     pm = PassManager(DEFAULT_PASSES if passes is None else passes)
-    return pm.compile(spec, core_fn=core_fn, use_pallas=use_pallas,
-                      analyze=analyze, analyze_per_pass=analyze_per_pass)
+    return pm.compile(spec, core_fn=core_fn, analyze=analyze,
+                      analyze_per_pass=analyze_per_pass)

@@ -9,10 +9,10 @@ All sweeps run on a shared :class:`SweepExecutor`, the bulk-evaluation
 engine behind the paper's "fast design space exploration" claim: it caches
 ``RoutingResources``/``FabricModule`` per interconnect, evaluates
 independent design points concurrently, and emulates every routed app of a
-design point as one batched ``FabricModule.run_batch`` scan — the fused
-XLA engine (PE cores evaluated in the fused step, per-app depth
-masking; ``use_pallas=True`` swaps in the Pallas kernels, interpret mode
-only), sharded across devices when more than one is visible.
+design point as one batched ``FabricModule.run_batch`` scan — the chain
+engine (``repro.kernels.fabric_chain``: PE cores evaluated inside the
+fixpoint, per-app depth masking), sharded across devices when more than
+one is visible.
 
 Design points are :class:`repro.core.spec.InterconnectSpec` objects (legacy
 kwargs dicts are canonicalized into specs on entry), and every executor
@@ -82,7 +82,8 @@ class SweepExecutor:
     across design points (``RoutingResources`` for the router,
     ``FabricModule`` for emulation), independent points run concurrently on
     a thread pool (JAX releases the GIL during device compute), and all
-    routed apps of a point are emulated as a single batch. Records
+    routed apps of a point are emulated as a single batch, one
+    ``run_batch`` call of the fabric's chain engine. Records
     accumulate on the executor and can be persisted as JSON for
     ``benchmarks/run.py``.
     """
@@ -92,13 +93,12 @@ class SweepExecutor:
                  alphas: Sequence[float] = _UNSET,
                  split_fifo_ctrl_delay: float = _UNSET,
                  max_workers: Optional[int] = None,
-                 emulate_cycles: int = 0, use_pallas: bool = False,
+                 emulate_cycles: int = 0,
                  shard: Optional[bool] = None, seed: int = _UNSET,
                  route_strategy: str = "auto",
                  place_strategy: str = "auto",
                  reg_penalty: float = _UNSET,
                  pipeline_emulation: bool = True,
-                 io_chunk: Optional[int] = None,
                  store: Any = None):
         self.apps = apps or BENCH_APPS
         self.sa_steps = self._folded_knob("sa_steps", sa_steps)
@@ -108,10 +108,6 @@ class SweepExecutor:
             "split_fifo_ctrl_delay", split_fifo_ctrl_delay)
         self.max_workers = max_workers
         self.emulate_cycles = emulate_cycles
-        #: emulation engine: the XLA engine (False) compiles everywhere;
-        #: the Pallas fabric kernels run only in interpret mode (Mosaic
-        #: refuses their gathers, see repro.core.lowering._check_engine)
-        self.use_pallas = use_pallas
         self.shard = shard
         self.seed = self._folded_knob("seed", seed)
         #: router engine (repro.core.pnr.route): "auto" routes big fabrics
@@ -122,9 +118,6 @@ class SweepExecutor:
         self.place_strategy = place_strategy
         self.reg_penalty = self._folded_knob("reg_penalty", reg_penalty)
         self.pipeline_emulation = pipeline_emulation
-        #: ext-IO streaming chunk for long stimulus traces (HBM-gridded
-        #: fused kernel); None keeps the per-cycle scan
-        self.io_chunk = io_chunk
         #: persistent spec-addressed result store: a ResultStore, a root
         #: path, False (disable even if the env names a store), or None —
         #: attach the CANAL_RESULT_STORE store when the env var is set
@@ -266,7 +259,7 @@ class SweepExecutor:
         with self._lock:
             fab = self._fab_cache.get(key)
         if fab is None:
-            fab = compile_interconnect(ic, use_pallas=self.use_pallas)
+            fab = compile_interconnect(ic)
             with self._lock:
                 fab = self._fab_cache.setdefault(key, fab)
         return fab
@@ -296,7 +289,6 @@ class SweepExecutor:
 
     def _submit_emulation(self, fab, routed: List[Tuple[str, Any, Any]],
                           out: Dict[str, Dict],
-                          io_chunk: Optional[int] = None,
                           on_done: Optional[Callable[[], None]] = None,
                           pending: Optional[List[Future]] = None
                           ) -> Future:
@@ -312,8 +304,7 @@ class SweepExecutor:
         pool, dev = self._emu_queue()
 
         def work():
-            emu = self._emulate_batch(fab, routed, device=dev,
-                                      io_chunk=io_chunk)
+            emu = self._emulate_batch(fab, routed, device=dev)
             for name, info in emu.items():
                 out[name]["emulation"] = info
             if on_done is not None:
@@ -372,7 +363,6 @@ class SweepExecutor:
     # ----------------------------------------------------- point execution
     def emulate_routed(self, fab, routed: List[Tuple[str, Any, Any]],
                        device: Any = None,
-                       io_chunk: Optional[int] = None,
                        stimulus: Optional[Dict[str, Dict[str, Any]]] = None
                        ) -> Dict[str, Tuple[int, Dict]]:
         """Emulate all routed apps of one design point as a single batch.
@@ -390,8 +380,6 @@ class SweepExecutor:
         import numpy as np
         from repro.fabric import AppEmulator, run_apps_batch
 
-        if io_chunk is None:
-            io_chunk = self.io_chunk
         T = self.emulate_cycles
         if stimulus is not None:
             lengths = {len(words) for drive in stimulus.values()
@@ -416,23 +404,19 @@ class SweepExecutor:
         if device is not None:
             import jax
             with jax.default_device(device):
-                outs = run_apps_batch(emulators, inputs, T, shard=False,
-                                      io_chunk=io_chunk)
+                outs = run_apps_batch(emulators, inputs, T, shard=False)
         else:
-            outs = run_apps_batch(emulators, inputs, T, shard=self.shard,
-                                  io_chunk=io_chunk)
+            outs = run_apps_batch(emulators, inputs, T, shard=self.shard)
         return {name: (emu.depth, out)
                 for name, emu, out in zip(names, emulators, outs)}
 
     def _emulate_batch(self, fab, routed: List[Tuple[str, Any, Any]],
-                       device: Any = None,
-                       io_chunk: Optional[int] = None) -> Dict[str, Dict]:
+                       device: Any = None) -> Dict[str, Dict]:
         """The bulk validation pass of the batched DSE engine: emulate
         the routed apps (:meth:`emulate_routed`) and record each app's
         depth, cycle count and output checksum."""
         with trace.span("emulate.batch"):
-            outs = self.emulate_routed(fab, routed, device=device,
-                                       io_chunk=io_chunk)
+            outs = self.emulate_routed(fab, routed, device=device)
         return {name: {"depth": depth, "cycles": self.emulate_cycles,
                        "out_checksum": out_checksum(out)}
                 for name, (depth, out) in outs.items()}
@@ -740,8 +724,7 @@ class SweepExecutor:
                      "emulate_cycles": self.emulate_cycles}
         if routed and not defer_emulation:
             fab = self.fabric(ic, key)
-            emu = self._emulate_batch(
-                fab, routed, io_chunk=spec.emulate_io_chunk or self.io_chunk)
+            emu = self._emulate_batch(fab, routed)
             for name, info in emu.items():
                 out[name]["emulation"] = info
         # wall time includes interconnect generation (cache misses pay it,
@@ -758,7 +741,6 @@ class SweepExecutor:
             # store must never serve a half-built record
             emu_fut = self._submit_emulation(
                 self.fabric(ic, key), routed, out,
-                io_chunk=spec.emulate_io_chunk or self.io_chunk,
                 on_done=lambda: self._store_put(spec, rec),
                 pending=pending)
         else:
@@ -1004,149 +986,3 @@ def generation_speed(sizes: Sequence[int] = (4, 8, 16, 32)) -> List[Dict]:
         recs.append({"size": s, "nodes": fab.arrays.num_nodes,
                      "gen_seconds": t1 - t0, "lower_seconds": t2 - t1})
     return recs
-
-
-def batched_vs_serial_emulation(width: int = 6, height: int = 6,
-                                num_tracks: int = 4, batch: int = 8,
-                                cycles: int = 16, use_pallas: bool = False,
-                                seed: int = 0) -> Dict:
-    """Micro-DSE: emulate B random fabric configurations serially
-    (``run`` per config) vs as one batch (``run_batch``). Returns wall
-    clocks and asserts bit-identical observations — the engine behind
-    ``benchmarks/dse_speed.py``'s batched-vs-serial comparison."""
-    import numpy as np
-    import jax.numpy as jnp
-
-    fab, cfgs, ext, depths = _random_fabric_workload(
-        width, height, num_tracks, batch, cycles, use_pallas, seed)
-    depth = int(depths.max())
-
-    # warm both paths once so neither timed region is dominated by one-off
-    # JIT/Pallas compilation (the comparison is dispatch cost, not compile)
-    fab.run(jnp.asarray(cfgs[0]), jnp.asarray(ext[0, :2]), depth=depth)
-    fab.run_batch(jnp.asarray(cfgs), jnp.asarray(ext[:, :2]), depth=depth)
-
-    t0 = time.perf_counter()
-    serial = np.stack([
-        np.asarray(fab.run(jnp.asarray(cfgs[b]), jnp.asarray(ext[b]),
-                           depth=depth))
-        for b in range(batch)])
-    serial_s = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    batched = np.asarray(fab.run_batch(jnp.asarray(cfgs),
-                                       jnp.asarray(ext), depth=depth))
-    batched_s = time.perf_counter() - t0
-
-    if not np.array_equal(serial, batched):
-        raise AssertionError("batched emulation diverged from serial")
-    return {"batch": batch, "cycles": cycles, "nodes": fab.arrays.num_nodes,
-            "depth": depth, "use_pallas": use_pallas,
-            "serial_seconds": serial_s, "batched_seconds": batched_s,
-            "speedup": serial_s / max(batched_s, 1e-9)}
-
-
-def _random_fabric_workload(width: int, height: int, num_tracks: int,
-                            batch: int, cycles: int, use_pallas: bool,
-                            seed: int):
-    """Shared fixture for the engine benchmarks: a compiled fabric plus
-    random configs / IO streams / per-config depths."""
-    import numpy as np
-    from .lowering import compile_interconnect
-    from .passes import PassManager
-
-    ic = PassManager().run(InterconnectSpec(
-        width=width, height=height, num_tracks=num_tracks, io_ring=True,
-        sb_type=SwitchBoxType.WILTON, reg_density=1.0))
-    fab = compile_interconnect(ic, use_pallas=use_pallas)
-    rng = np.random.default_rng(seed)
-    cfgs = rng.integers(0, 4, (batch, fab.num_config)).astype(np.int32)
-    ext = rng.integers(0, 256, (batch, cycles, fab.num_io)).astype(np.int32)
-    depths = np.array([fab.combinational_depth(c) for c in cfgs], np.int32)
-    return fab, cfgs, ext, depths
-
-
-def _timed_min(fn, repeats: int) -> Tuple[Any, float]:
-    """Best-of-N wall clock: the min is far less sensitive to scheduler
-    noise on shared runners than a single shot."""
-    best = float("inf")
-    out = None
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        out = fn()
-        best = min(best, time.perf_counter() - t0)
-    return out, best
-
-
-def fused_vs_unfused_emulation(width: int = 6, height: int = 6,
-                               num_tracks: int = 4, batch: int = 8,
-                               cycles: int = 16, use_pallas: bool = False,
-                               seed: int = 0, repeats: int = 3) -> Dict:
-    """The fused batched engine (whole fixpoint + PE eval in one kernel
-    call per cycle) vs the sweep-at-a-time PR-1 baseline (one batched
-    gather kernel launch per sweep, Python-level PE evaluation between
-    launches). Same workload, per-config depths, bit-identical outputs
-    asserted — the measured margin is pure fusion."""
-    import numpy as np
-    import jax.numpy as jnp
-
-    fab, cfgs, ext, depths = _random_fabric_workload(
-        width, height, num_tracks, batch, cycles, use_pallas, seed)
-    cj, ej = jnp.asarray(cfgs), jnp.asarray(ext)
-
-    # warm both engines on the full shapes so the timed regions compare
-    # execution, not tracing/compilation
-    fab.run_batch(cj, ej, depth=depths, fused=False, shard=False)
-    fab.run_batch(cj, ej, depth=depths, fused=True, shard=False)
-
-    unfused, unfused_s = _timed_min(
-        lambda: np.asarray(fab.run_batch(cj, ej, depth=depths,
-                                         fused=False, shard=False)),
-        repeats)
-    fused, fused_s = _timed_min(
-        lambda: np.asarray(fab.run_batch(cj, ej, depth=depths,
-                                         fused=True, shard=False)),
-        repeats)
-    if not np.array_equal(unfused, fused):
-        raise AssertionError("fused engine diverged from unfused baseline")
-    return {"batch": batch, "cycles": cycles,
-            "nodes": fab.arrays.num_nodes, "use_pallas": use_pallas,
-            "max_depth": int(depths.max()), "min_depth": int(depths.min()),
-            "unfused_seconds": unfused_s, "fused_seconds": fused_s,
-            "speedup": unfused_s / max(fused_s, 1e-9)}
-
-
-def sharded_vs_single_emulation(width: int = 5, height: int = 5,
-                                num_tracks: int = 3, batch: int = 8,
-                                cycles: int = 8, use_pallas: bool = False,
-                                seed: int = 0, repeats: int = 3) -> Dict:
-    """``run_batch`` with the batch axis shard_map'ed across every visible
-    device vs the same workload on one device. Bit-identical outputs
-    asserted. With a single visible device the sharded call takes the
-    local fallback, so the record degenerates to a no-regression check;
-    run under ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (or
-    on a real multi-chip topology) to see the split."""
-    import numpy as np
-    import jax
-    import jax.numpy as jnp
-
-    fab, cfgs, ext, depths = _random_fabric_workload(
-        width, height, num_tracks, batch, cycles, use_pallas, seed)
-    cj, ej = jnp.asarray(cfgs), jnp.asarray(ext)
-
-    fab.run_batch(cj, ej, depth=depths, shard=False)
-    fab.run_batch(cj, ej, depth=depths, shard=True)
-
-    single, single_s = _timed_min(
-        lambda: np.asarray(fab.run_batch(cj, ej, depth=depths,
-                                         shard=False)), repeats)
-    sharded, sharded_s = _timed_min(
-        lambda: np.asarray(fab.run_batch(cj, ej, depth=depths,
-                                         shard=True)), repeats)
-    if not np.array_equal(single, sharded):
-        raise AssertionError("sharded emulation diverged from single-device")
-    return {"batch": batch, "cycles": cycles,
-            "nodes": fab.arrays.num_nodes, "use_pallas": use_pallas,
-            "devices": len(jax.devices()),
-            "single_seconds": single_s, "sharded_seconds": sharded_s,
-            "speedup": single_s / max(sharded_s, 1e-9)}

@@ -13,14 +13,16 @@ Because the structural graph contains *potential* combinational cycles
 (register-bypass muxes), the fabric evaluates each cycle by fixpoint
 sweeps: one sweep propagates every node's value one combinational level.
 A legal configuration's active network is acyclic, so ``depth`` sweeps
-(≥ longest configured combinational path) reach the fixed point. The
-batched path runs the whole fixpoint — PE cores included — as one engine
-call per cycle, masks each configuration to its own combinational depth,
-and shards the batch axis across devices (``run_batch``). Its served
-engine (``repro.kernels.fabric_chain``) collapses every chain of selected
-inputs to its root once a batch, so a sweep evaluates only the PE input
-slots; the N-wide sweep has Pallas kernels (``repro.kernels.fabric_step``)
-and an oracle (``repro.kernels.ref``).
+(≥ longest configured combinational path) reach the fixed point.
+
+Two engines evaluate it. The per-config ``step``/``run`` sweeps every
+node, one configuration at a time: the serial reference of the tests and
+the engine of ``RVFabric``. The batched ``step_batch``/``run_batch`` runs
+the chain engine (``repro.kernels.fabric_chain``): it collapses every
+chain of selected inputs to its root once a batch, so a sweep evaluates
+only the 4P PE input slots. It masks each configuration to its own
+combinational depth and shards the batch axis across devices. Its test
+oracle is the N-wide sweep ``repro.kernels.ref.fabric_fused_batch_ref``.
 """
 from __future__ import annotations
 
@@ -34,32 +36,16 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.kernels import fabric_chain
-from repro.kernels.fabric_step import PE_OPS, pe_alu_candidates
+from repro.kernels.fabric_chain import PE_OPS, pe_alu_candidates
 
-from .graph import IO, Interconnect, Node, NodeKind
+from .graph import Interconnect, Node, NodeKind
 from .tiles import IOCore, MemCore, PECore, WORD
 
 assert PECore.OPS == PE_OPS, \
-    "fabric_step.PE_OPS must mirror PECore.OPS (shared PE ALU datapath)"
+    "fabric_chain.PE_OPS must mirror PECore.OPS (shared PE ALU datapath)"
 PE_OP_IDS = {op: i for i, op in enumerate(PECore.OPS)}
 
 DepthSpec = Union[int, np.ndarray, jnp.ndarray]
-
-
-def _check_engine(use_pallas: bool) -> None:
-    """Refuse the Pallas fabric engine where it cannot compile.
-
-    Mosaic, the TPU's Pallas compiler, refuses the ``fabric_step``
-    kernels' gathers (the 1-D ``jnp.take`` of ``fabric_sweep*`` and the
-    fused kernels alike: "Only 2D gather is supported"). Fail here, at
-    construction, instead of deep inside lowering on the first step."""
-    if use_pallas and jax.default_backend() == "tpu":
-        raise NotImplementedError(
-            "FabricModule(use_pallas=True) cannot run on TPU: Mosaic "
-            "refuses the 1-D gathers of the repro.kernels.fabric_step "
-            "kernels. Use the XLA engine (use_pallas=False, the default); "
-            "a Mosaic rewrite of the fabric gathers is the 'Emulation "
-            "engine choice' item of ROADMAP.md.")
 
 
 def _delayed_as_immediates(reg_mask, imm_mask, imm_val, pe_regs):
@@ -70,21 +56,6 @@ def _delayed_as_immediates(reg_mask, imm_mask, imm_val, pe_regs):
     delayed = reg_mask > 0
     return (jnp.where(delayed, 1, imm_mask),
             jnp.where(delayed, pe_regs, imm_val))
-
-
-def _refuse_delays_streamed(pe_cfgs: Dict[str, jnp.ndarray]) -> None:
-    """The streamed engine carries no PE input registers across cycles:
-    refuse a program that delays any port rather than answer wrongly."""
-    if "reg_mask" not in pe_cfgs:
-        return
-    try:
-        delayed = bool(np.any(np.asarray(pe_cfgs["reg_mask"])))
-    except jax.errors.TracerArrayConversionError:
-        delayed = True
-    if delayed:
-        raise NotImplementedError(
-            "the streamed engine (io_chunk) does not carry PE input "
-            "registers across cycles; run delayed ports without io_chunk")
 
 
 @dataclass
@@ -124,19 +95,17 @@ class FabricModule:
     the layer bit width.
     """
 
-    def __init__(self, ic: Interconnect, use_pallas: bool = False):
-        _check_engine(use_pallas)
+    def __init__(self, ic: Interconnect):
         self.ic = ic
-        self.use_pallas = use_pallas
         self.nodes: List[Node] = list(ic.nodes())
         self.node_id: Dict[Node, int] = {n: i for i, n in
                                          enumerate(self.nodes)}
         self.config_slots: List[ConfigSlot] = []
         #: run_batch's program, jitted once: JAX keys it on the static
-        #: loop bound and engine (an eagerly called scan would be traced
-        #: and compiled again on every call)
+        #: loop bound (an eagerly called scan would be traced and
+        #: compiled again on every call)
         self._run_batch_jit = jax.jit(self._run_batch_local,
-                                      static_argnums=(4, 5, 6))
+                                      static_argnums=(4,))
         #: its sharded form, one per device tuple
         self._sharded_programs: Dict[Tuple, object] = {}
         self._build_tables()
@@ -244,8 +213,8 @@ class FabricModule:
         self._build_fused_tables()
 
     def _build_fused_tables(self) -> None:
-        """Node/PE tables for the fused batched engine (one kernel call per
-        fixpoint): hold-flags, pin mask, sentinel-padded PE inputs and the
+        """Node/PE tables for the batched engine and its sweep oracle:
+        hold-flags, pin mask, sentinel-padded PE inputs and the
         scatter-free node -> PE-result index map."""
         a = self.arrays
         n = a.num_nodes
@@ -276,58 +245,37 @@ class FabricModule:
             "read": np.concatenate([pe_in.ravel(), a.reg_src, self.mem_in,
                                     self.io_out_nodes]).astype(np.int32),
         }
-        self._stream_tables: Optional[Dict[str, np.ndarray]] = None
         self._chain_consts: Optional[Dict[str, np.ndarray]] = None
-
-    def stream_tables(self) -> Dict[str, np.ndarray]:
-        """Node tables for the streamed fused engine: the node → state
-        gather map for scatter-free per-cycle re-pinning. State layout is
-        ``[regs | ext io | mem | zero]``; every non-pinned node points at
-        the trailing zero slot."""
-        if self._stream_tables is None:
-            a = self.arrays
-            n_reg = len(a.reg_ids)
-            s_len = n_reg + self.num_io + self.num_mem + 1
-            pin_src = np.full(a.num_nodes, s_len - 1, dtype=np.int32)
-            if n_reg:
-                pin_src[a.reg_ids] = np.arange(n_reg, dtype=np.int32)
-            if self.num_io:
-                pin_src[self.io_in_nodes] = n_reg + np.arange(
-                    self.num_io, dtype=np.int32)
-            if self.num_mem:
-                pin_src[self.mem_out] = n_reg + self.num_io + np.arange(
-                    self.num_mem, dtype=np.int32)
-            self._stream_tables = {
-                "pin_src": pin_src,
-                "reg_src": a.reg_src.astype(np.int32),
-                "mem_in": self.mem_in.astype(np.int32),
-                "io_out": self.io_out_nodes.astype(np.int32),
-                "n_reg": n_reg,
-            }
-        return self._stream_tables
 
     def chain_consts(self) -> Dict[str, np.ndarray]:
         """Node tables for the chain engine (``kernels/fabric_chain.py``),
         each (N + 1,) with the sentinel: ``root`` (held, pinned and PE
-        output nodes), ``res`` (``pe_res_idx``) and ``pin_src`` (the
-        streamed engine's node -> pinned-state map)."""
+        output nodes), ``res`` (``pe_res_idx``) and ``pin_src``, the node
+        -> pinned-state map. The pinned state is ``[regs | ext io | mem |
+        zero]``; every unpinned node points at the trailing zero."""
         if self._chain_consts is None:
             a, t = self.arrays, self.fused_tables
             n, p = a.num_nodes, t["num_pe_slots"]
-            pin_src = self.stream_tables()["pin_src"]
+            n_reg = len(a.reg_ids)
+            s_zero = n_reg + self.num_io + self.num_mem
+            pin_src = np.full(n + 1, s_zero, dtype=np.int32)
+            pin_src[a.reg_ids] = np.arange(n_reg, dtype=np.int32)
+            pin_src[self.io_in_nodes] = n_reg + np.arange(
+                self.num_io, dtype=np.int32)
+            pin_src[self.mem_out] = n_reg + self.num_io + np.arange(
+                self.num_mem, dtype=np.int32)
             outs = self.pe_out.ravel()
             if np.any(a.is_driven[outs]) or np.any(t["pin_mask"][outs]):
                 raise NotImplementedError(
                     "the chain engine needs every PE output node undriven "
                     "and unpinned")
             res = np.append(t["pe_res_idx"], 2 * p).astype(np.int32)
-            s_zero = len(a.reg_ids) + self.num_io + self.num_mem
             self._chain_consts = {
                 "root": np.append(
                     (t["keep"] | t["pin_mask"]) | (res[:n] < 2 * p),
                     1).astype(np.int32),
                 "res": res,
-                "pin_src": np.append(pin_src, s_zero).astype(np.int32),
+                "pin_src": pin_src,
             }
         return self._chain_consts
 
@@ -338,11 +286,8 @@ class FabricModule:
 
     @property
     def slots_per_sweep(self) -> int:
-        """Node values the fused engine gathers per lane per sweep: the
-        4P PE input slots on the chain engine (XLA), every node on the
-        Pallas sweep engines."""
-        if self.use_pallas:
-            return self.arrays.num_nodes
+        """Node values the batched engine gathers per lane per sweep: the
+        4P PE input slots."""
         return 4 * self.fused_tables["num_pe_slots"]
 
     def init_state(self) -> Dict[str, jnp.ndarray]:
@@ -392,42 +337,15 @@ class FabricModule:
                                             0))
 
     def _sweep(self, vals_ext: jnp.ndarray, sel: jnp.ndarray) -> jnp.ndarray:
-        """One combinational propagation sweep: the fabric hot loop.
+        """One combinational propagation sweep of the per-config engine.
         vals_ext has the zero sentinel appended (length N+1); returns (N,).
         """
         a = self.arrays
-        if self.use_pallas:
-            from repro.kernels import ops as kops
-            new = kops.fabric_sweep(vals_ext, jnp.asarray(a.src), sel)
-        else:
-            src_sel = jnp.take_along_axis(
-                jnp.asarray(a.src), sel[:, None], axis=1)[:, 0]
-            new = vals_ext[src_sel]
+        src_sel = jnp.take_along_axis(
+            jnp.asarray(a.src), sel[:, None], axis=1)[:, 0]
+        new = vals_ext[src_sel]
         keep = jnp.asarray(~a.is_driven)
         return jnp.where(keep, vals_ext[:-1], new) \
-                  .astype(jnp.int32)
-
-    def _sweep_batch(self, vals_ext: jnp.ndarray,
-                     sel: jnp.ndarray) -> jnp.ndarray:
-        """Batched sweep: vals_ext (B, N+1), sel (B, N) -> (B, N).
-
-        With ``use_pallas`` the batched kernel vectorizes over the
-        configuration axis (bitstream-major layout); otherwise the single
-        sweep is vmapped."""
-        a = self.arrays
-        src = jnp.asarray(a.src)
-        if self.use_pallas:
-            from repro.kernels import ops as kops
-            new = kops.fabric_sweep_batch(vals_ext, src, sel)
-        else:
-            def one(v_ext, s):
-                src_sel = jnp.take_along_axis(src, s[:, None],
-                                              axis=1)[:, 0]
-                return v_ext[src_sel]
-
-            new = jax.vmap(one)(vals_ext, sel)
-        keep = jnp.asarray(~a.is_driven)
-        return jnp.where(keep[None, :], vals_ext[:, :-1], new) \
                   .astype(jnp.int32)
 
     def _eval_pes(self, vals: jnp.ndarray,
@@ -553,10 +471,8 @@ class FabricModule:
         return jnp.asarray(depth, jnp.int32), int(max_depth)
 
     def _norm_pe_cfg(self, pe_cfg: Dict[str, jnp.ndarray], b: int,
-                     pe_regs: Optional[jnp.ndarray] = None
-                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
-                                jnp.ndarray]:
-        """PE program tables shaped for the fused kernel: (B, P) op/const
+                     pe_regs: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:
+        """PE program tables shaped for the batched engine: (B, P) op/const
         and (B, P, 4) immediates, P = max(num_pe, 1) slots. Delayed ports
         (``reg_mask``) become immediates holding ``pe_regs``, the (B, P,
         4) values their ports carried last cycle."""
@@ -574,7 +490,7 @@ class FabricModule:
             return jnp.pad(x, ((0, 0), (0, p - npe), (0, 0)))
 
         imm_mask, imm_val = pad3("imm_mask"), pad3("imm_val")
-        if pe_regs is not None and "reg_mask" in pe_cfg:
+        if "reg_mask" in pe_cfg:
             imm_mask, imm_val = _delayed_as_immediates(
                 pad3("reg_mask"), imm_mask, imm_val, pe_regs)
         return pad2(pe_cfg["op"]), pad2(pe_cfg["const"]), imm_mask, imm_val
@@ -584,7 +500,6 @@ class FabricModule:
                    pe_cfg: Optional[Dict[str, jnp.ndarray]] = None,
                    depth: DepthSpec = 16,
                    max_depth: Optional[int] = None,
-                   fused: Optional[bool] = None,
                    chains: Optional[Dict[str, jnp.ndarray]] = None
                    ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
         """One fabric clock cycle for B configurations at once.
@@ -597,40 +512,28 @@ class FabricModule:
         ``depth`` is either a shared int or a (B,) per-configuration sweep
         count: every lane runs the static ``max_depth`` loop but freezes
         once its own count is reached, so each configuration performs
-        exactly its own fixpoint. ``fused`` (default True) runs the whole
-        fixpoint — PE evaluation included — as one engine call: on XLA
-        (``use_pallas=False``, the served engine) the chain engine of
-        ``kernels/fabric_chain.py``, whose sweeps evaluate only the 4P PE
-        input slots; with ``use_pallas`` the ``fabric_fused_batch``
-        kernel. ``ref.fabric_fused_batch_ref``, the N-wide sweep oracle,
-        is their test reference. ``fused=False`` keeps the
-        sweep-at-a-time loop (per-sweep batched gather + Python-level PE
-        evaluation), bit-identical, as the unfused baseline. ``chains``
-        are the chain engine's per-batch tables (``chain_tables``), built
-        here when not given."""
+        exactly its own fixpoint. The fixpoint, PE evaluation included,
+        is one call of the chain engine (``kernels/fabric_chain.py``),
+        whose sweeps evaluate only the 4P PE input slots;
+        ``ref.fabric_fused_batch_ref``, the N-wide sweep oracle, is its
+        test reference. ``chains`` are its per-batch tables
+        (``chain_tables``), built here when not given."""
         b = config.shape[0]
         if pe_cfg is None:
             pe_cfg = self.default_pe_cfg_batch(b)
-        if fused is None:
-            fused = True
         a = self.arrays
         depths, max_depth = self._norm_depth(depth, max_depth, b)
         op, const, imm_mask, imm_val = self._norm_pe_cfg(
             pe_cfg, b, state["pe_regs"])
-        if fused and not self.use_pallas:
-            if chains is None:
-                chains = self.chain_tables(config, max_depth)
-            pin_state = jnp.concatenate(
-                [state["regs"], ext_in.astype(jnp.int32),
-                 state["mem"][:, :self.num_mem],
-                 jnp.zeros((b, 1), jnp.int32)], axis=1)
-            reads = fabric_chain.chain_fixpoint(
-                chains, pin_state, depths, op, const, imm_mask, imm_val,
-                max_depth=max_depth, word=WORD)
-        else:
-            reads = self._sweep_fixpoint(state, ext_in, config, depths,
-                                         max_depth, fused, op, const,
-                                         imm_mask, imm_val)
+        if chains is None:
+            chains = self.chain_tables(config, max_depth)
+        pin_state = jnp.concatenate(
+            [state["regs"], ext_in.astype(jnp.int32),
+             state["mem"][:, :self.num_mem],
+             jnp.zeros((b, 1), jnp.int32)], axis=1)
+        reads = fabric_chain.chain_fixpoint(
+            chains, pin_state, depths, op, const, imm_mask, imm_val,
+            max_depth=max_depth, word=WORD)
         p = self.fused_tables["num_pe_slots"]
         n_reg = len(a.reg_ids)
         new_state = dict(state)
@@ -655,104 +558,22 @@ class FabricModule:
             jnp.asarray(c["pin_src"]),
             jnp.asarray(self.fused_tables["read"]), max_depth)
 
-    def _sweep_fixpoint(self, state, ext_in, config, depths, max_depth,
-                        fused, op, const, imm_mask, imm_val) -> jnp.ndarray:
-        """The N-wide sweep engines of ``step_batch`` (Pallas fused, or
-        unfused): the fixpoint's values at the read-out nodes
-        (``fused_tables["read"]``), (B, K)."""
-        a = self.arrays
-        b = config.shape[0]
-        sel = jax.vmap(self._selects)(config)          # (B, N)
-
-        def pin(v):
-            if len(a.reg_ids):
-                v = v.at[:, jnp.asarray(a.reg_ids)].set(state["regs"])
-            if self.num_io:
-                v = v.at[:, jnp.asarray(self.io_in_nodes)].set(
-                    ext_in.astype(jnp.int32))
-            if self.num_mem:
-                v = v.at[:, jnp.asarray(self.mem_out)].set(
-                    state["mem"][:, :self.num_mem])
-            return v
-
-        # pinned sources on a zero background double as the initial values
-        pin_vals = pin(jnp.zeros((b, a.num_nodes), dtype=jnp.int32))
-
-        t = self.fused_tables
-        if fused:
-            from repro.kernels import ops as kops
-            vals = kops.fabric_fused_batch(
-                pin_vals, sel, pin_vals, depths, op, const, imm_mask,
-                imm_val, jnp.asarray(a.src),
-                jnp.asarray(t["keep"]), jnp.asarray(t["pin_mask"]),
-                jnp.asarray(t["pe_in"]), jnp.asarray(t["pe_res_idx"]),
-                max_depth=max_depth, word=WORD)
-        else:
-            lane_cfg = {"op": op, "const": const, "imm_mask": imm_mask,
-                        "imm_val": imm_val}
-
-            def body(i, v):
-                v_ext = jnp.concatenate(
-                    [v, jnp.zeros((b, 1), jnp.int32)], axis=1)
-                nv = self._sweep_batch(v_ext, sel)
-                nv = pin(nv)
-                nv = jax.vmap(self._eval_pes)(nv, lane_cfg)
-                return jnp.where((i < depths)[:, None], nv, v)
-
-            vals = jax.lax.fori_loop(0, max_depth, body, pin_vals)
-
-        vals_ext = jnp.concatenate(
-            [vals, jnp.zeros((b, 1), jnp.int32)], axis=1)
-        return vals_ext[:, jnp.asarray(t["read"])]
-
-    def _run_batch_stream(self, configs: jnp.ndarray, ext: jnp.ndarray,
-                          pe_cfgs: Dict[str, jnp.ndarray],
-                          depths: jnp.ndarray, max_depth: int,
-                          io_chunk: int) -> jnp.ndarray:
-        """Streamed fused engine: the whole T-cycle emulation in one
-        kernel invocation, ext-IO gridded from HBM in ``io_chunk``-cycle
-        blocks instead of materializing (B, T, io) beside the value
-        matrices in VMEM. Bit-identical to the per-cycle scan."""
-        from repro.kernels import ops as kops
-
-        a = self.arrays
-        b = configs.shape[0]
-        sel = jax.vmap(self._selects)(configs)
-        op, const, imm_mask, imm_val = self._norm_pe_cfg(pe_cfgs, b)
-        t = self.fused_tables
-        s = self.stream_tables()
-        return kops.fabric_fused_run(
-            sel, ext, depths, op, const, imm_mask, imm_val,
-            jnp.asarray(a.src), jnp.asarray(t["keep"]),
-            jnp.asarray(t["pin_mask"]), jnp.asarray(s["pin_src"]),
-            jnp.asarray(t["pe_in"]), jnp.asarray(t["pe_res_idx"]),
-            jnp.asarray(s["reg_src"]), jnp.asarray(s["mem_in"]),
-            jnp.asarray(s["io_out"]), n_reg=s["n_reg"],
-            n_io=self.num_io, n_mem=self.num_mem, max_depth=max_depth,
-            chunk=io_chunk, word=WORD)
-
     @jax.named_scope("canal.emulate")
     def _run_batch_local(self, configs: jnp.ndarray, ext: jnp.ndarray,
                          pe_cfgs: Dict[str, jnp.ndarray],
-                         depths: jnp.ndarray, max_depth: int,
-                         fused: Optional[bool],
-                         io_chunk: Optional[int] = None) -> jnp.ndarray:
-        """One device's share of ``run_batch``: scan T cycles over a
-        (local) batch of configurations — or, with ``io_chunk`` on the
-        Pallas fused engine, one streamed multi-cycle kernel call."""
-        if io_chunk and self.use_pallas and (fused is None or fused):
-            return self._run_batch_stream(configs, ext, pe_cfgs, depths,
-                                          max_depth, io_chunk)
+                         depths: jnp.ndarray, max_depth: int
+                         ) -> jnp.ndarray:
+        """One device's share of ``run_batch``: the chain tables once,
+        then a scan of T cycles over a (local) batch of configurations."""
         b = configs.shape[0]
         state = self.init_state_batch(b)
         xs = jnp.swapaxes(ext, 0, 1)                    # (T, B, io)
-        chains = (self.chain_tables(configs, max_depth)
-                  if fused is not False and not self.use_pallas else None)
+        chains = self.chain_tables(configs, max_depth)
 
         def scan_fn(st, x):
             st, obs = self.step_batch(st, x, configs, pe_cfgs,
                                       depth=depths, max_depth=max_depth,
-                                      fused=fused, chains=chains)
+                                      chains=chains)
             return st, obs
 
         _, out = jax.lax.scan(scan_fn, state, xs)
@@ -761,9 +582,7 @@ class FabricModule:
     def run_batch(self, configs: jnp.ndarray, ext_streams: jnp.ndarray,
                   pe_cfgs: Optional[Dict[str, jnp.ndarray]] = None,
                   depth: Optional[DepthSpec] = None,
-                  fused: Optional[bool] = None,
-                  shard: Optional[bool] = None,
-                  io_chunk: Optional[int] = None) -> jnp.ndarray:
+                  shard: Optional[bool] = None) -> jnp.ndarray:
         """Evaluate B configurations in one ``lax.scan``.
 
         configs: (B, num_config); ext_streams: (B, T, num_io); pe_cfgs
@@ -778,21 +597,8 @@ class FabricModule:
         ``shard`` (default: auto, on when >1 device) splits the batch axis
         across ``jax.devices()`` via shard_map, padding B up to a multiple
         of the device count; on a single device the local path runs
-        unsharded. ``fused`` selects the fused engine (default: the chain
-        engine on XLA, whose tables are built once a batch, before the
-        scan; the Pallas kernel with ``use_pallas``) or the
-        sweep-at-a-time baseline.
-
-        ``io_chunk`` streams the external IO from HBM in chunks of that
-        many cycles through the fused multi-cycle kernel
-        (``fabric_fused_run``) instead of scanning one kernel call per
-        cycle — for long stimulus traces only (B, io_chunk, io) of the
-        stimulus is resident per grid step. Requires ``use_pallas`` and
-        the fused engine; otherwise it is ignored (the reference scan
-        already keeps the trace in host/HBM memory). Bit-identical to the
-        unstreamed path either way; a PE program with delayed ports
-        (``reg_mask``) is refused there, since the streamed kernel
-        carries no PE input registers."""
+        unsharded. Each cycle is one ``step_batch`` of the chain engine,
+        whose tables are built once a batch, before the scan."""
         configs = jnp.asarray(configs)
         ext = jnp.asarray(ext_streams)
         b = configs.shape[0]
@@ -807,15 +613,12 @@ class FabricModule:
         max_depth = int(depths_np.max()) if b else 1
         if pe_cfgs is None:
             pe_cfgs = self.default_pe_cfg_batch(b)
-        if io_chunk and self.use_pallas and (fused is None or fused):
-            _refuse_delays_streamed(pe_cfgs)
         devices = jax.devices()
         n_dev = len(devices)
         use_shard = (n_dev > 1) if shard is None else shard
         if not use_shard or n_dev <= 1 or b == 0:
             return self._run_batch_jit(configs, ext, pe_cfgs,
-                                       jnp.asarray(depths_np), max_depth,
-                                       fused, io_chunk)
+                                       jnp.asarray(depths_np), max_depth)
 
         bp = -(-b // n_dev) * n_dev                     # ceil to devices
         pad = bp - b
@@ -827,8 +630,7 @@ class FabricModule:
         out = self._sharded_program(tuple(devices))(
             pad_b(configs), pad_b(ext),
             {k: pad_b(v) for k, v in pe_cfgs.items()},
-            jnp.asarray(np.pad(depths_np, (0, pad))), max_depth, fused,
-            io_chunk)
+            jnp.asarray(np.pad(depths_np, (0, pad))), max_depth)
         return out[:b] if pad else out
 
     def _sharded_program(self, devices: Tuple):
@@ -839,13 +641,11 @@ class FabricModule:
             return program
         spec = PartitionSpec("b")
 
-        def sharded(c, e, p, d, max_depth, fused, io_chunk):
+        def sharded(c, e, p, d, max_depth):
             def local(c, e, p, d):
-                return self._run_batch_local(c, e, p, d, max_depth, fused,
-                                             io_chunk)
+                return self._run_batch_local(c, e, p, d, max_depth)
 
-            # check_vma=False: shard_map has no varying-axes rule for
-            # pallas_call; every operand/output is explicitly
+            # check_vma=False: every operand/output is explicitly
             # batch-sharded
             return jax.shard_map(
                 local, mesh=Mesh(np.array(devices), ("b",)),
@@ -853,7 +653,7 @@ class FabricModule:
                 check_vma=False)(c, e, p, d)
 
         return self._sharded_programs.setdefault(
-            devices, jax.jit(sharded, static_argnums=(4, 5, 6)))
+            devices, jax.jit(sharded, static_argnums=(4,)))
 
     # ------------------------------------------------- combinational depth
     def _selected_src_host(self, config: np.ndarray) -> np.ndarray:
@@ -996,7 +796,6 @@ class FabricModule:
         return out
 
 
-def compile_interconnect(ic: Interconnect,
-                         use_pallas: bool = False) -> FabricModule:
+def compile_interconnect(ic: Interconnect) -> FabricModule:
     """The static-backend entry point (IR → hardware, §3.3)."""
-    return FabricModule(ic, use_pallas=use_pallas)
+    return FabricModule(ic)

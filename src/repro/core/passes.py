@@ -387,7 +387,6 @@ class PassManager:
 
     def compile(self, spec: InterconnectSpec,
                 core_fn: Optional[CoreFn] = None,
-                use_pallas: bool = False,
                 analyze: str = "warn",
                 analyze_per_pass: bool = False):
         """The front door: spec -> CompiledFabric.
@@ -418,7 +417,6 @@ class PassManager:
             if analyze == "error":
                 report.raise_if("error")
         return CompiledFabric(spec, ic, pass_log=ctx.log,
-                              use_pallas=use_pallas,
                               cacheable=core_fn is None,
                               diagnostics=report)
 

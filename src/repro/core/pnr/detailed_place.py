@@ -134,7 +134,6 @@ def detailed_place(packed: PackedGraph,
                    n_steps: int = 300, batch: int = 64,
                    t0: float = 2.0, t_min: float = 0.01,
                    seed: int = 0,
-                   use_pallas: bool = False,
                    strategy: str = "python"
                    ) -> Dict[str, Tuple[int, int]]:
     """Anneal the legalized placement. Only movable (pe/mem) instances move;

@@ -76,10 +76,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "CANAL_RESULT_STORE, else .canal_store)")
     g.add_argument("--no-store", action="store_true",
                    help="run cold: no persistent memoization")
-    g.add_argument("--pallas", action="store_true",
-                   help="emulate with the Pallas fabric kernels "
-                        "(interpret mode only, refused on TPU; default: "
-                        "the XLA engine)")
     p.add_argument("-o", "--output", default=None, metavar="FILE",
                    help="write the JSON document here (default: "
                         "stdout)")
@@ -145,8 +141,7 @@ def run(argv: Optional[List[str]] = None) -> int:
                     constraints=constraints or None,
                     budget=ns.budget, batch_size=ns.batch,
                     seed=ns.seed, store=store, apps=apps,
-                    emulate_cycles=ns.emulate_cycles,
-                    use_pallas=ns.pallas)
+                    emulate_cycles=ns.emulate_cycles)
     best = result.best(ns.objective, constraints or None)
     doc = {"selector": ns.selector,
            "objective": ns.objective,

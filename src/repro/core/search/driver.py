@@ -42,7 +42,6 @@ def search(base: Optional[InterconnectSpec] = None,
            executor: Any = None, store: Any = None,
            apps: Optional[Dict] = None, emulate_cycles: int = 0,
            selector_options: Optional[Dict] = None,
-           use_pallas: bool = False,
            max_workers: Optional[int] = None,
            **executor_kwargs) -> SearchResult:
     """Search-driven design-space exploration over ``InterconnectSpec``
@@ -59,7 +58,7 @@ def search(base: Optional[InterconnectSpec] = None,
 
     An existing ``executor`` (e.g. a :class:`DSEService`'s) is reused
     as configured; otherwise one is built from ``store`` / ``apps`` /
-    ``emulate_cycles`` / ``use_pallas`` and the remaining kwargs.
+    ``emulate_cycles`` and the remaining kwargs.
     Returns a :class:`SearchResult` — ``frontier`` (non-dominated valid
     points), ``evaluated`` (everything), ``stats`` (round counts plus
     the executor counter deltas, so "zero new PnR on the re-run" is one
@@ -82,7 +81,6 @@ def search(base: Optional[InterconnectSpec] = None,
         from ..dse import SweepExecutor
         executor = SweepExecutor(apps=apps, store=store,
                                  emulate_cycles=emulate_cycles,
-                                 use_pallas=use_pallas,
                                  max_workers=max_workers,
                                  **executor_kwargs)
 

@@ -92,7 +92,8 @@ class InterconnectSpec:
     route_strategy: Optional[str] = None   # None = caller default
     #: "auto" strategy threshold override (tiles); None = env/module default
     auto_min_tiles: Optional[int] = None
-    #: ext-IO streaming chunk for batched emulation; None = caller default
+    #: retired: read by no engine. Kept because every canonical form
+    #: serializes it (as null), so dropping it changes every digest
     emulate_io_chunk: Optional[int] = None
     # PnR knobs folded from SweepExecutor (PR 5): a design point now fully
     # describes *how* it is placed and routed, so its digest addresses the

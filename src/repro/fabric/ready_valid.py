@@ -38,13 +38,12 @@ from repro.core.lowering import FabricModule
 class RVFabric(FabricModule):
     """Hybrid ready-valid interconnect functional model."""
 
-    def __init__(self, ic: Interconnect, fifo_mode: str = "split",
-                 use_pallas: bool = False):
+    def __init__(self, ic: Interconnect, fifo_mode: str = "split"):
         if fifo_mode not in ("full", "split"):
             raise ValueError("fifo_mode must be 'full' or 'split'")
         self.fifo_mode = fifo_mode
         self.fifo_depth = 2 if fifo_mode == "full" else 1
-        super().__init__(ic, use_pallas=use_pallas)
+        super().__init__(ic)
         self._build_reverse_tables()
 
     # ------------------------------------------------------------------ build
@@ -335,7 +334,7 @@ class RVFabric(FabricModule):
         return outs
 
 
-def compile_ready_valid(ic: Interconnect, fifo_mode: str = "split",
-                        use_pallas: bool = False) -> RVFabric:
+def compile_ready_valid(ic: Interconnect,
+                        fifo_mode: str = "split") -> RVFabric:
     """Ready-valid backend entry point (the hybrid interconnect, §3.3)."""
-    return RVFabric(ic, fifo_mode=fifo_mode, use_pallas=use_pallas)
+    return RVFabric(ic, fifo_mode=fifo_mode)

@@ -102,16 +102,14 @@ class AppEmulator:
 def run_apps_batch(emulators: Sequence[AppEmulator],
                    inputs_list: Sequence[Dict[Tuple[int, int], np.ndarray]],
                    cycles: int,
-                   shard: Optional[bool] = None,
-                   io_chunk: Optional[int] = None
+                   shard: Optional[bool] = None
                    ) -> List[Dict[Tuple[int, int], np.ndarray]]:
     """Emulate several routed applications on the *same* fabric as one
     batch: all configs/PE programs/IO streams advance together through a
     single ``FabricModule.run_batch`` scan. The engine is the chain
     engine (``kernels/fabric_chain.py``), whose sweeps evaluate only the
-    4P PE input slots; the fused batched Pallas kernel when the fabric
-    was compiled with ``use_pallas=True``. Both are tested against the
-    N-wide sweep oracle, ``kernels/ref.py`` ``fabric_fused_batch_ref``.
+    4P PE input slots; it is tested against the N-wide sweep oracle,
+    ``kernels/ref.py`` ``fabric_fused_batch_ref``.
 
     Each app sweeps exactly its own routed combinational depth — lanes
     with shallower routes freeze early instead of padding to the batch
@@ -119,9 +117,6 @@ def run_apps_batch(emulators: Sequence[AppEmulator],
     zip(...)]`` while compiling one program for the whole batch — the DSE
     bulk-evaluation path. ``shard`` forwards to ``run_batch``: the app
     axis is split across devices when more than one is visible.
-    ``io_chunk`` forwards too: on the Pallas fused engine, long stimulus
-    traces stream from HBM in chunks of that many cycles instead of
-    materializing (B, T, io) next to the value matrices.
 
     The ``emulate.run`` span records ``sweeps`` (the static sweep bound
     times the cycles) and ``slots_per_sweep``, the node values the
@@ -143,7 +138,7 @@ def run_apps_batch(emulators: Sequence[AppEmulator],
         pe_cfgs = {k: jnp.stack([e.pe_cfg[k] for e in emulators])
                    for k in emulators[0].pe_cfg}
         out = fab.run_batch(configs, jnp.asarray(ext), pe_cfgs=pe_cfgs,
-                            depth=depths, shard=shard, io_chunk=io_chunk)
+                            depth=depths, shard=shard)
         with trace.span("device.wait"):
             obs = np.asarray(out)
         return [{c: obs[b, :, i] for c, i in e.io_index.items()}

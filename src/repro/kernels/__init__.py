@@ -1,9 +1,7 @@
-"""Pallas kernels (validated in interpret mode on CPU).
+"""Device kernels (Pallas ones validated in interpret mode on CPU).
 
-- fabric_step: batched CGRA fabric sweep (the paper's generated hardware);
-  interpret mode only — Mosaic refuses its gathers for TPU
-- fabric_chain: the served XLA emulation engine (not Pallas): the
-  fixpoint collapsed onto the PE input slots
+- fabric_chain: the fabric's batched emulation engine (XLA, not Pallas):
+  the fixpoint collapsed onto the PE input slots, and the PE ALU datapath
 - hpwl: per-net bounding-box reduction for SA placement; compiles for
   TPU v5e
 - minplus: tropical relaxation for batched routing wavefronts; compiles

@@ -1,5 +1,5 @@
-"""Chain-collapsed fixpoint: the served XLA engine of the fabric's batched
-emulation (``FabricModule.step_batch``, fused, ``use_pallas=False``).
+"""Chain-collapsed fixpoint: the engine of the fabric's batched emulation
+(``FabricModule.step_batch``), and the PE ALU datapath it evaluates.
 
 Within one fabric cycle the mux selects are fixed, so every sweep of the
 fixpoint copies into each driven node the value its selected input held
@@ -32,7 +32,28 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from .fabric_step import PE_OPS, pe_alu_candidates
+# PE ALU candidate order; must match repro.core.tiles.PECore.OPS
+# (repro.core.lowering asserts the correspondence at import time).
+PE_OPS = ("add", "sub", "mul", "and", "or", "xor", "shl", "shr", "min",
+          "max", "abs", "sel", "const", "pass")
+
+
+def pe_alu_candidates(a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray,
+                      const: jnp.ndarray) -> jnp.ndarray:
+    """All PE ALU results, stacked (n_ops, ...) in ``PE_OPS`` order.
+
+    The device copy of the PE datapath: the chain engine, its sweep
+    oracle (``ref.fabric_fused_batch_ref``) and the per-config
+    ``FabricModule._eval_pes`` select rows out of this stack with the
+    configured opcode. Shifts take the amount's low four bits, as
+    ``PECore.evaluate`` does."""
+    shift_b = b & 15
+    return jnp.stack([
+        a + b, a - b, a * b, a & b, a | b, a ^ b,
+        a << shift_b, a >> shift_b, jnp.minimum(a, b),
+        jnp.maximum(a, b), jnp.abs(a - b),
+        jnp.where((a & 1) == 1, b, c), const, a,
+    ], axis=0)
 
 
 def chain_tables(sel: jnp.ndarray, src: jnp.ndarray, root: jnp.ndarray,

@@ -21,7 +21,7 @@ The kernel compiles for TPU v5e at 1024 nets
 
 ``interpret`` resolves per call from the active backend (compiled on
 TPU, interpret elsewhere — CPU has no Mosaic backend), exactly like
-``fabric_step`` / ``minplus``; pass an explicit bool to pin it.
+``minplus``; pass an explicit bool to pin it.
 
 Validated against ``ref.hpwl_ref`` / ``ref.net_bboxes_ref``.
 """
@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .fabric_step import _default_interpret
+from .minplus import _default_interpret
 
 BLOCK_NETS = 256
 SENTINEL = 1 << 20

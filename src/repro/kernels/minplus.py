@@ -49,9 +49,10 @@ INF = jnp.float32(3.0e38) / 4
 
 
 def _default_interpret() -> bool:
-    """Compiled on TPU, interpret elsewhere — resolved per call (mirrors
-    ``fabric_step._default_interpret``; a mid-process backend swap must
-    not see a stale value)."""
+    """Compiled on TPU, interpret elsewhere (CPU has no Mosaic backend).
+
+    Resolved per call, here and in ``hpwl`` and ``ops``: a mid-process
+    backend swap must not see a stale value."""
     return jax.default_backend() != "tpu"
 
 

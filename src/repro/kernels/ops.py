@@ -6,65 +6,18 @@ so a mid-process platform swap (tests forcing ``jax.default_backend``)
 picks the right mode.
 
 Mosaic compiles ``minplus_*``, ``hpwl`` and ``net_bboxes`` for TPU v5e
-(``tests/test_tpu_compile.py``). It refuses the four ``fabric_*``
-kernels (their gathers), so on TPU these wrappers are unreachable from
-the fabric model, which emulates with the XLA engine.
+(``tests/test_tpu_compile.py``). The fabric's emulation engine is XLA
+(``fabric_chain``) and has no wrapper here.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-from . import fabric_step as _fabric
 from . import flash_attention as _flash
 from . import hpwl as _hpwl
 from . import minplus as _minplus
 from . import ssd_scan as _ssd
-from .fabric_step import _default_interpret as _interpret
-
-
-def fabric_sweep(vals_ext: jnp.ndarray, src: jnp.ndarray,
-                 sel: jnp.ndarray) -> jnp.ndarray:
-    return _fabric.fabric_sweep(vals_ext, src, sel, interpret=_interpret())
-
-
-def fabric_sweep_batch(vals_ext: jnp.ndarray, src: jnp.ndarray,
-                       sel: jnp.ndarray) -> jnp.ndarray:
-    return _fabric.fabric_sweep_batch(vals_ext, src, sel,
-                                      interpret=_interpret())
-
-
-def fabric_fused_batch(vals0: jnp.ndarray, sel: jnp.ndarray,
-                       pin_vals: jnp.ndarray, depths: jnp.ndarray,
-                       op: jnp.ndarray, const: jnp.ndarray,
-                       imm_mask: jnp.ndarray, imm_val: jnp.ndarray,
-                       src: jnp.ndarray, keep: jnp.ndarray,
-                       pin_mask: jnp.ndarray, pe_in: jnp.ndarray,
-                       pe_res_idx: jnp.ndarray, max_depth: int,
-                       word: int = 0xFFFF) -> jnp.ndarray:
-    """Fused batched fixpoint: masked sweeps + in-kernel PE evaluation."""
-    return _fabric.fabric_fused_batch(
-        vals0, sel, pin_vals, depths, op, const, imm_mask, imm_val,
-        src, keep, pin_mask, pe_in, pe_res_idx, max_depth=max_depth,
-        word=word, interpret=_interpret())
-
-
-def fabric_fused_run(sel: jnp.ndarray, ext: jnp.ndarray,
-                     depths: jnp.ndarray, op: jnp.ndarray,
-                     const: jnp.ndarray, imm_mask: jnp.ndarray,
-                     imm_val: jnp.ndarray, src: jnp.ndarray,
-                     keep: jnp.ndarray, pin_mask: jnp.ndarray,
-                     pin_src: jnp.ndarray, pe_in: jnp.ndarray,
-                     pe_res_idx: jnp.ndarray, reg_src: jnp.ndarray,
-                     mem_in: jnp.ndarray, io_out: jnp.ndarray,
-                     n_reg: int, n_io: int, n_mem: int, max_depth: int,
-                     chunk: int = 8, word: int = 0xFFFF) -> jnp.ndarray:
-    """Streamed fused emulation: T cycles in one kernel, ext-IO gridded
-    from HBM in ``chunk``-cycle blocks."""
-    return _fabric.fabric_fused_run(
-        sel, ext, depths, op, const, imm_mask, imm_val, src, keep,
-        pin_mask, pin_src, pe_in, pe_res_idx, reg_src, mem_in, io_out,
-        n_reg=n_reg, n_io=n_io, n_mem=n_mem, max_depth=max_depth,
-        chunk=chunk, word=word, interpret=_interpret())
+from .minplus import _default_interpret as _interpret
 
 
 def hpwl(pins: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:

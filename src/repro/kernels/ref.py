@@ -1,20 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness contracts)."""
+"""Pure-jnp oracles for the kernels (the correctness contracts)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-
-def fabric_sweep_ref(vals_ext: jnp.ndarray, src: jnp.ndarray,
-                     sel: jnp.ndarray) -> jnp.ndarray:
-    """out[i] = vals[src[i, sel[i]]]."""
-    picked = jnp.take_along_axis(src, sel[:, None], axis=1)[:, 0]
-    return vals_ext[picked]
-
-
-def fabric_sweep_batch_ref(vals_ext: jnp.ndarray, src: jnp.ndarray,
-                           sel: jnp.ndarray) -> jnp.ndarray:
-    return jax.vmap(lambda v, s: fabric_sweep_ref(v, src, s))(vals_ext, sel)
+from .fabric_chain import pe_alu_candidates
 
 
 def fabric_fused_batch_ref(vals0: jnp.ndarray, sel: jnp.ndarray,
@@ -25,13 +15,11 @@ def fabric_fused_batch_ref(vals0: jnp.ndarray, sel: jnp.ndarray,
                            pin_mask: jnp.ndarray, pe_in: jnp.ndarray,
                            pe_out: jnp.ndarray, max_depth: int,
                            word: int = 0xFFFF) -> jnp.ndarray:
-    """Scatter-based oracle for ``fabric_fused_batch``: a vmapped lane
-    loop of gather -> hold-undriven -> re-pin -> PE-eval sweeps, each lane
-    frozen once its own ``depths`` count is reached. Same contract as the
-    kernel except PE outputs are named by ``pe_out`` (n_pe, n_cols) node
-    ids instead of the kernel's flattened ``pe_res_idx`` map."""
-    from .fabric_step import pe_alu_candidates
-
+    """The N-wide sweep oracle of the chain engine
+    (``fabric_chain.chain_fixpoint``): a vmapped lane loop of gather ->
+    hold-undriven -> re-pin -> PE-eval sweeps over every node, each lane
+    frozen once its own ``depths`` count is reached. PE outputs are named
+    by ``pe_out`` (n_pe, n_cols) node ids. Returns the (B, N) values."""
     n_pe = pe_out.shape[0]
 
     def lane(v0, s, pv, d, o, cst, im, iv):

@@ -288,8 +288,7 @@ def serve(store: Optional[Union[ResultStore, str]] = None,
     ``store`` is a :class:`ResultStore`, a root path, or None (honor
     ``CANAL_RESULT_STORE``, else ``.canal_store``); remaining kwargs go
     to the underlying :class:`SweepExecutor` (``apps=``,
-    ``emulate_cycles=``, ...). Its emulation engine defaults to the XLA
-    one (``use_pallas=False``), the engine that compiles for TPU."""
+    ``emulate_cycles=``, ...)."""
     if isinstance(store, str):
         store = ResultStore(store)
     return DSEService(store=store, **kwargs)

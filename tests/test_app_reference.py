@@ -120,14 +120,20 @@ def test_fir_impulse_response():
         reference.evaluate(app_fir(4), {"in0": x}, 8)["out0"], y)
 
 
-@pytest.mark.parametrize("use_pallas,fused", [(False, True), (False, False),
-                                              (True, True)])
-def test_run_apps_batch_matches_per_app_with_delays(use_pallas, fused):
-    """A batch of fir, stencil and pointwise (delayed and plain ports in
-    one batch) equals each app's own ``run``, on every engine."""
+#: app sets batched together: delayed and plain ports in one batch; all
+#: five, the amber_full.emulate cell's B = 5; the sequential apps alone
+APP_SETS = {"fir-stencil-pointwise": ("fir", "stencil", "pointwise"),
+            "all": tuple(BENCH_APPS), "fir": ("fir",),
+            "stencil": ("stencil",)}
+
+
+@pytest.mark.parametrize("apps", sorted(APP_SETS))
+def test_run_apps_batch_matches_per_app_with_delays(apps):
+    """A batch of routed apps equals each app's own ``run`` and the
+    plain app reference, lane for lane."""
     f = routed("8x8_mem4")
-    fab = compile_interconnect(f.ic, use_pallas=use_pallas)
-    names = ("fir", "stencil", "pointwise")
+    fab = f.fab
+    names = APP_SETS[apps]
     rs = [f.pnr(n) for n in names]
     emus = [AppEmulator.from_pnr(fab, r.packed, r) for r in rs]
     stims = [_stimulus(r.packed.app, seed=k) for k, r in enumerate(rs)]
@@ -137,7 +143,7 @@ def test_run_apps_batch_matches_per_app_with_delays(use_pallas, fused):
         jnp.stack([e.config for e in emus]), jnp.asarray(ext),
         pe_cfgs={k: jnp.stack([e.pe_cfg[k] for e in emus])
                  for k in emus[0].pe_cfg},
-        depth=np.array([e.depth for e in emus]), fused=fused))
+        depth=np.array([e.depth for e in emus])))
     for b, (e, i, r, s) in enumerate(zip(emus, ins, rs, stims)):
         single = e.run(i, T)
         for coord, k in e.io_index.items():
@@ -149,24 +155,6 @@ def test_run_apps_batch_matches_per_app_with_delays(use_pallas, fused):
     for e, i, got in zip(emus, ins, batched):
         for coord, words in e.run(i, T).items():
             np.testing.assert_array_equal(got[coord], words)
-
-
-@pytest.mark.parametrize("name", ["fir", "pointwise"])
-def test_streamed_engine_equal_or_refuses(name):
-    """The streamed engine carries no PE input registers: it refuses an
-    app with delayed ports and stays bit-identical on one without."""
-    f = routed("8x8_mem4")
-    fab = compile_interconnect(f.ic, use_pallas=True)
-    r = f.pnr(name)
-    emu = AppEmulator.from_pnr(fab, r.packed, r)
-    ins = _on_fabric(r, _stimulus(r.packed.app, seed=3))
-    if r.packed.delayed_ports():
-        with pytest.raises(NotImplementedError):
-            run_apps_batch([emu], [ins], T, io_chunk=4)
-        return
-    got, = run_apps_batch([emu], [ins], T, io_chunk=4)
-    for coord, words in emu.run(ins, T).items():
-        np.testing.assert_array_equal(got[coord], words)
 
 
 @pytest.mark.parametrize("name,cut", [("fir", "absorbed register d3"),

@@ -30,23 +30,32 @@ def _random_cases(fab, b, t, seed=0):
     return cfgs, ext
 
 
-@pytest.mark.parametrize("use_pallas,fused", [(False, True),
-                                              (False, False),
-                                              (True, True),
-                                              (True, False)])
-def test_run_batch_matches_looped_run(small_ic, use_pallas, fused):
-    """B configurations through one run_batch == B serial run calls —
-    the Pallas/fused variant exercises fabric_fused_batch end to end,
-    the unfused one the sweep-at-a-time fabric_sweep_batch baseline."""
-    fab = compile_interconnect(small_ic, use_pallas=use_pallas)
-    cfgs, ext = _random_cases(fab, b=4, t=5)
+@pytest.fixture(scope="module")
+def mem_fabric():
+    ic = create_uniform_interconnect(width=4, height=4, num_tracks=2,
+                                     sb_type="wilton", io_ring=True,
+                                     reg_density=1.0, mem_columns=(2,))
+    fab = compile_interconnect(ic)
+    assert fab.num_mem > 0
+    return fab
+
+
+@pytest.mark.parametrize("kind,t", [("plain", 1), ("plain", 4),
+                                    ("plain", 7), ("plain", 16),
+                                    ("memory", 9)])
+def test_run_batch_matches_looped_run(request, kind, t):
+    """B configurations through one run_batch == B serial run calls, for
+    stimulus lengths from one cycle up, and on a fabric whose memory
+    tiles carry state from cycle to cycle."""
+    fab = request.getfixturevalue("fabric" if kind == "plain"
+                                  else "mem_fabric")
+    cfgs, ext = _random_cases(fab, b=4, t=t, seed=t)
     serial = np.stack([
         np.asarray(fab.run(jnp.asarray(cfgs[i]), jnp.asarray(ext[i]),
                            depth=8))
         for i in range(len(cfgs))])
     batched = np.asarray(fab.run_batch(jnp.asarray(cfgs),
-                                       jnp.asarray(ext), depth=8,
-                                       fused=fused))
+                                       jnp.asarray(ext), depth=8))
     np.testing.assert_array_equal(serial, batched)
 
 
@@ -153,67 +162,6 @@ def test_run_apps_batch_rejects_mixed_fabrics(small_ic, fabric):
         run_apps_batch([e1, e2], [{}, {}], 4)
 
 
-@pytest.mark.parametrize("chunk", [1, 4, 16])
-def test_run_batch_io_chunk_streams_bit_identically(small_ic, chunk):
-    """The streamed fused kernel (ext-IO gridded from HBM in chunk-cycle
-    blocks, register/mem state carried across grid steps) must be
-    bit-identical to the per-cycle scan — including T not divisible by
-    the chunk and per-config computed depths."""
-    fab = compile_interconnect(small_ic, use_pallas=True)
-    cfgs, ext = _random_cases(fab, b=3, t=7)
-    base = np.asarray(fab.run_batch(jnp.asarray(cfgs), jnp.asarray(ext),
-                                    depth=8))
-    stream = np.asarray(fab.run_batch(jnp.asarray(cfgs), jnp.asarray(ext),
-                                      depth=8, io_chunk=chunk))
-    np.testing.assert_array_equal(base, stream)
-
-
-def test_run_batch_io_chunk_streams_mem_state_bit_identically():
-    """Memory cores exercise the third state region of the streamed
-    kernel (mem_out pin slots, mem_in gather): a mem-bearing fabric must
-    stream bit-identically to the per-cycle scan too."""
-    ic = create_uniform_interconnect(width=4, height=4, num_tracks=2,
-                                     sb_type="wilton", io_ring=True,
-                                     reg_density=1.0, mem_columns=(2,))
-    fab = compile_interconnect(ic, use_pallas=True)
-    assert fab.num_mem > 0
-    cfgs, ext = _random_cases(fab, b=3, t=9)
-    base = np.asarray(fab.run_batch(jnp.asarray(cfgs), jnp.asarray(ext),
-                                    depth=8))
-    stream = np.asarray(fab.run_batch(jnp.asarray(cfgs), jnp.asarray(ext),
-                                      depth=8, io_chunk=4))
-    np.testing.assert_array_equal(base, stream)
-
-
-def test_run_batch_io_chunk_ignored_on_reference_engine(small_ic, fabric):
-    """Without the Pallas engine there is nothing to stream: io_chunk is
-    accepted and ignored (the scan already leaves the trace off-chip)."""
-    cfgs, ext = _random_cases(fabric, b=2, t=5)
-    a = np.asarray(fabric.run_batch(jnp.asarray(cfgs), jnp.asarray(ext),
-                                    depth=8))
-    b = np.asarray(fabric.run_batch(jnp.asarray(cfgs), jnp.asarray(ext),
-                                    depth=8, io_chunk=4))
-    np.testing.assert_array_equal(a, b)
-
-
-def test_run_apps_batch_io_chunk_matches(small_ic):
-    """run_apps_batch forwards io_chunk; routed-app emulation streamed
-    from HBM stays bit-identical to the unstreamed batch."""
-    from repro.fabric import AppEmulator, run_apps_batch
-
-    fab = compile_interconnect(small_ic, use_pallas=True)
-    e1 = AppEmulator(fab, _east_route(small_ic, y=1), pe_ops={})
-    e2 = AppEmulator(fab, _east_route(small_ic, y=2), pe_ops={})
-    T = 9
-    i1 = {(0, 1): np.arange(10, 10 + T, dtype=np.int32)}
-    i2 = {(0, 2): np.arange(50, 50 + T, dtype=np.int32)}
-    plain = run_apps_batch([e1, e2], [i1, i2], T)
-    streamed = run_apps_batch([e1, e2], [i1, i2], T, io_chunk=4)
-    for got, want in zip(streamed, plain):
-        for coord in want:
-            np.testing.assert_array_equal(got[coord], want[coord])
-
-
 def test_pipelined_emulation_matches_inline():
     """The async PnR/emulation pipeline (deferred per-device dispatch,
     futures joined before records return) must produce the same records
@@ -230,7 +178,7 @@ def test_pipelined_emulation_matches_inline():
     for pipelined in (False, True):
         ex = SweepExecutor(apps={"pw1": lambda: app_pointwise(1)},
                            sa_steps=20, sa_batch=8, emulate_cycles=8,
-                           use_pallas=False, max_workers=2,
+                           max_workers=2,
                            pipeline_emulation=pipelined)
         recs[pipelined] = ex.run_points(points)
         assert not ex._pending          # all futures joined
@@ -251,7 +199,7 @@ def test_sweep_executor_point_with_batched_emulation(tmp_path):
 
     ex = SweepExecutor(apps={"pw1": lambda: app_pointwise(1)},
                        sa_steps=20, sa_batch=8, emulate_cycles=8,
-                       use_pallas=False, max_workers=1)
+                       max_workers=1)
     kw = dict(width=6, height=6, num_tracks=4, io_ring=True,
               reg_density=1.0)
     recs = ex.run_points([(kw, {"num_tracks": 4})])
@@ -270,36 +218,3 @@ def test_sweep_executor_point_with_batched_emulation(tmp_path):
     import json
     with open(path) as f:
         assert json.load(f)[0]["num_tracks"] == 4
-
-
-def test_batched_vs_serial_emulation_equal_and_recorded():
-    from repro.core.dse import batched_vs_serial_emulation
-
-    rec = batched_vs_serial_emulation(width=4, height=4, num_tracks=2,
-                                      batch=3, cycles=4, use_pallas=False)
-    assert rec["batch"] == 3 and rec["serial_seconds"] > 0
-    assert rec["batched_seconds"] > 0
-
-
-def test_fused_vs_unfused_emulation_equal_and_recorded():
-    """The benchmark engine asserts fused == unfused internally; the
-    record carries the per-config depth spread it masked over."""
-    from repro.core.dse import fused_vs_unfused_emulation
-
-    rec = fused_vs_unfused_emulation(width=4, height=4, num_tracks=2,
-                                     batch=3, cycles=4, use_pallas=False)
-    assert rec["unfused_seconds"] > 0 and rec["fused_seconds"] > 0
-    assert rec["min_depth"] >= 1
-    assert rec["max_depth"] >= rec["min_depth"]
-
-
-def test_sharded_vs_single_emulation_single_device_fallback():
-    """On one visible device the sharded call must take the local path
-    and stay bit-identical (asserted inside the engine)."""
-    from repro.core.dse import sharded_vs_single_emulation
-
-    rec = sharded_vs_single_emulation(width=4, height=4, num_tracks=2,
-                                      batch=3, cycles=4,
-                                      use_pallas=False)
-    assert rec["devices"] >= 1
-    assert rec["single_seconds"] > 0 and rec["sharded_seconds"] > 0

@@ -1,15 +1,14 @@
-"""Differential harness for the fabric emulators: the fused batched
-engine must be bit-identical to the serial per-config reference.
+"""Differential harness for the fabric emulators: the batched engine
+must be bit-identical to the serial per-config reference.
 
 Hypothesis-driven (through ``tests/_hypothesis_compat``): random
 interconnect geometries, random (often combinationally-cyclic) configs,
-random PE programs and stream lengths, checked on both the vmap oracle
-path (``use_pallas=False``) and the Pallas interpret path
-(``use_pallas=True``), and — in a subprocess with forced host devices —
-on the shard_map multi-device path.
+random PE programs and stream lengths, checked on one device and — in a
+subprocess with forced host devices — on the shard_map multi-device
+path.
 
-The served XLA engine, the chain engine (``kernels/fabric_chain.py``),
-is also checked against the N-wide sweep oracle
+The batched engine, the chain engine (``kernels/fabric_chain.py``), is
+also checked against the N-wide sweep oracle
 ``ref.fabric_fused_batch_ref``: on every node when called directly, and
 on every read-out of ``step_batch`` over several cycles."""
 import functools
@@ -43,9 +42,8 @@ def _ic(width, height, num_tracks):
 
 
 @functools.lru_cache(maxsize=None)
-def _fabric(width, height, num_tracks, use_pallas):
-    return compile_interconnect(_ic(width, height, num_tracks),
-                                use_pallas=use_pallas)
+def _fabric(width, height, num_tracks):
+    return compile_interconnect(_ic(width, height, num_tracks))
 
 
 def _random_workload(fab, rng, batch, cycles):
@@ -65,49 +63,66 @@ def _random_workload(fab, rng, batch, cycles):
     return cfgs, ext, pe_cfgs
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-@given(st.integers(3, 4), st.integers(1, 4), st.sampled_from([3, 5, 7]),
-       st.integers(0, 2**31 - 1))
-@settings(max_examples=4, deadline=None)
-def test_run_batch_bit_identical_to_serial(use_pallas, size, batch,
-                                           cycles, seed):
-    """run_batch (fused and unfused) == per-config run, lane for lane,
-    with per-config combinational depths — even for random configs whose
-    active network is cyclic, thanks to masked early exit."""
-    fab = _fabric(size, size, 2, use_pallas)
-    rng = np.random.default_rng(seed)
-    cfgs, ext, pe_cfgs = _random_workload(fab, rng, batch, cycles)
-    serial = np.stack([
+def _diff_fabric(kind, size):
+    """A small fabric: ``plain``, or ``memory``, one size up, with a
+    memory column (at 3x3 the column would fall on the IO ring)."""
+    if kind == "memory":
+        fab = _mem_fabric(size + 1, 2, (2,))
+        assert fab.num_mem > 0
+        return fab
+    return _fabric(size, size, 2)
+
+
+def _serial(fab, cfgs, ext, pe_cfgs):
+    """Per-config ``run`` of every lane, stacked: the serial reference."""
+    return np.stack([
         np.asarray(fab.run(
             jnp.asarray(cfgs[i]), jnp.asarray(ext[i]),
             pe_cfg={k: jnp.asarray(v[i]) for k, v in pe_cfgs.items()}))
-        for i in range(batch)])
-    for fused in (True, False):
-        batched = np.asarray(fab.run_batch(
-            jnp.asarray(cfgs), jnp.asarray(ext),
-            pe_cfgs={k: jnp.asarray(v) for k, v in pe_cfgs.items()},
-            fused=fused))
-        np.testing.assert_array_equal(
-            serial, batched,
-            err_msg=f"use_pallas={use_pallas} fused={fused} seed={seed}")
+        for i in range(len(cfgs))])
 
 
-@given(st.integers(0, 2**31 - 1))
-@settings(max_examples=3, deadline=None)
-def test_pallas_and_vmap_paths_agree(seed):
-    """The Pallas-interpret engine and the pure-jnp oracle engine produce
-    the same observations for the same workload."""
+def _batched(fab, cfgs, ext, pe_cfgs):
+    return np.asarray(fab.run_batch(
+        jnp.asarray(cfgs), jnp.asarray(ext),
+        pe_cfgs={k: jnp.asarray(v) for k, v in pe_cfgs.items()}))
+
+
+@pytest.mark.parametrize("kind", ["plain", "memory"])
+@given(st.integers(3, 4), st.integers(1, 4), st.sampled_from([3, 5, 7]),
+       st.integers(0, 2**31 - 1))
+@settings(max_examples=4, deadline=None)
+def test_run_batch_bit_identical_to_serial(kind, size, batch, cycles, seed):
+    """run_batch == per-config run, lane for lane, with per-config
+    combinational depths — even for random configs whose active network
+    is cyclic, thanks to masked early exit."""
+    fab = _diff_fabric(kind, size)
     rng = np.random.default_rng(seed)
-    batch, cycles = 3, 5
-    fab_ref = _fabric(4, 4, 2, False)
-    fab_pal = _fabric(4, 4, 2, True)
-    cfgs, ext, pe_cfgs = _random_workload(fab_ref, rng, batch, cycles)
-    kw = dict(pe_cfgs={k: jnp.asarray(v) for k, v in pe_cfgs.items()})
-    a = np.asarray(fab_ref.run_batch(jnp.asarray(cfgs), jnp.asarray(ext),
-                                     **kw))
-    b = np.asarray(fab_pal.run_batch(jnp.asarray(cfgs), jnp.asarray(ext),
-                                     **kw))
-    np.testing.assert_array_equal(a, b, err_msg=f"seed={seed}")
+    cfgs, ext, pe_cfgs = _random_workload(fab, rng, batch, cycles)
+    np.testing.assert_array_equal(
+        _serial(fab, cfgs, ext, pe_cfgs), _batched(fab, cfgs, ext, pe_cfgs),
+        err_msg=f"{kind} seed={seed}")
+
+
+@given(st.integers(2, 5), st.integers(0, 2**31 - 1))
+@settings(max_examples=4, deadline=None)
+def test_run_batch_lane_independent_of_batch(batch, seed):
+    """A lane's output does not depend on the lanes beside it: it equals
+    the lane's own B = 1 run, and permuting the lanes permutes the
+    outputs."""
+    fab = _fabric(4, 4, 2)
+    rng = np.random.default_rng(seed)
+    cfgs, ext, pe_cfgs = _random_workload(fab, rng, batch, 4)
+    full = _batched(fab, cfgs, ext, pe_cfgs)
+    k = int(rng.integers(batch))
+    one = _batched(fab, cfgs[k:k + 1], ext[k:k + 1],
+                   {n: v[k:k + 1] for n, v in pe_cfgs.items()})
+    np.testing.assert_array_equal(full[k], one[0], err_msg=f"lane {k}")
+    perm = rng.permutation(batch)
+    permuted = _batched(fab, cfgs[perm], ext[perm],
+                        {n: v[perm] for n, v in pe_cfgs.items()})
+    np.testing.assert_array_equal(permuted, full[perm],
+                                  err_msg=f"perm {perm}")
 
 
 @given(st.integers(1, 64), st.integers(0, 2**31 - 1))
@@ -115,7 +130,7 @@ def test_pallas_and_vmap_paths_agree(seed):
 def test_stream_length_invariance(prefix, seed):
     """Emulating T cycles then truncating == emulating the first T' < T
     cycles directly: the scan carries no hidden cross-cycle coupling."""
-    fab = _fabric(4, 4, 2, False)
+    fab = _fabric(4, 4, 2)
     rng = np.random.default_rng(seed)
     cycles = 8
     cfgs, ext, pe_cfgs = _random_workload(fab, rng, 2, cycles)
@@ -132,7 +147,7 @@ def test_stream_length_invariance(prefix, seed):
 def test_per_lane_depth_equals_per_config_runs():
     """Explicit heterogeneous depths: lane i must behave exactly like a
     serial run at depth_i, not at the batch max."""
-    fab = _fabric(4, 4, 2, False)
+    fab = _fabric(4, 4, 2)
     rng = np.random.default_rng(7)
     cfgs, ext, pe_cfgs = _random_workload(fab, rng, 4, 5)
     depths = np.array([2, 5, 9, 3], np.int32)
@@ -159,7 +174,7 @@ def test_sharded_run_batch_matches_single_device():
         "assert len(jax.devices()) == 4, jax.devices()\n"
         "ic = create_uniform_interconnect(width=3, height=3,"
         " num_tracks=2, sb_type='wilton', io_ring=True, reg_density=1.0)\n"
-        "fab = compile_interconnect(ic, use_pallas=False)\n"
+        "fab = compile_interconnect(ic)\n"
         "rng = np.random.default_rng(0)\n"
         "cfgs = rng.integers(0, 4, (6, fab.num_config)).astype(np.int32)\n"
         "ext = rng.integers(0, 999, (6, 4, fab.num_io)).astype(np.int32)\n"
@@ -214,7 +229,7 @@ def _chain_case(name):
         fab = _mem_fabric(c["size"], c["tracks"], c["mem_columns"])
         assert fab.num_mem > 0
     else:
-        fab = _fabric(c["size"], c["size"], c["tracks"], False)
+        fab = _fabric(c["size"], c["size"], c["tracks"])
     rng = np.random.default_rng(zlib.crc32(name.encode()))
     b, n = CHAIN_LANES, max(fab.num_pe, 1)
     p = fab.fused_tables["num_pe_slots"]
@@ -389,14 +404,13 @@ def test_chain_step_batch_matches_sweep_oracle_readouts(case):
 
 
 def test_served_xla_path_never_calls_sweep_oracle(monkeypatch):
-    """``run_batch`` on ``use_pallas=False`` runs the chain engine: with
-    the sweep oracle made to raise, a freshly traced batch still equals
-    the per-config runs."""
+    """``run_batch`` runs the chain engine: with the sweep oracle made to
+    raise, a freshly traced batch still equals the per-config runs."""
     def refuse(*args, **kwargs):
         raise AssertionError("the served path called the sweep oracle")
 
     monkeypatch.setattr(kref, "fabric_fused_batch_ref", refuse)
-    fab = compile_interconnect(_ic(3, 3, 2), use_pallas=False)
+    fab = compile_interconnect(_ic(3, 3, 2))
     rng = np.random.default_rng(16)
     cfgs, ext, pe_cfgs = _random_workload(fab, rng, 3, 4)
     batched = np.asarray(fab.run_batch(

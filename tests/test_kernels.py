@@ -1,77 +1,116 @@
 """Per-kernel interpret-mode validation against the pure-jnp oracles,
 sweeping shapes and dtypes (assignment requirement)."""
+import functools
+
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops, ref
+from repro.core.pnr import reference
+from repro.core.tiles import PECore
+from repro.kernels import fabric_chain, ops, ref
+from repro.kernels.fabric_chain import PE_OPS
+
+WORD = 0xFFFF
+
+
+def _alu_operands():
+    """Random 16-bit words, plus 0, 0xFFFF and the shift amounts 15, 16,
+    17 and 0xFFFF crossed with every corner of ``a``."""
+    rng = np.random.default_rng(14)
+    corners = np.array([0, 1, 15, 16, 17, 0x8000, WORD], np.int64)
+    a, b = (x.ravel() for x in np.meshgrid(corners, corners))
+    n = 256
+    a = np.concatenate([a, rng.integers(0, WORD + 1, n)])
+    b = np.concatenate([b, rng.integers(0, WORD + 1, n)])
+    c = rng.integers(0, WORD + 1, len(a))
+    const = rng.integers(0, WORD + 1, len(a))
+    return a, b, c, const
+
+
+@pytest.mark.parametrize("op", PE_OPS)
+def test_pe_alu_matches_reference(op):
+    """The device ALU (``pe_alu_candidates``) equals the plain app
+    reference's ``_alu`` and ``PECore.evaluate`` word for word; shifts
+    take the amount's low four bits."""
+    a, b, c, const = _alu_operands()
+    cand = fabric_chain.pe_alu_candidates(
+        *(jnp.asarray(x, jnp.int32) for x in (a, b, c, const)))
+    got = np.asarray(cand[PE_OPS.index(op)]) & WORD
+    np.testing.assert_array_equal(got, reference._alu(op, a, b, c, const),
+                                  err_msg=op)
+    want = [PECore.evaluate(op, [int(x), int(y), int(z)], int(k))
+            for x, y, z, k in zip(a, b, c, const)]
+    np.testing.assert_array_equal(got, want, err_msg=op)
+
+
+def _chain_case(n, f, b, seed):
+    """Random chain-engine tables over ``n`` nodes (sentinel ``n``): a
+    functional graph of selected inputs, cyclic in places (nodes 0 and
+    1 always select each other), roots, PE result and pinned-state
+    indices, every node read out."""
+    rng = np.random.default_rng(seed)
+    src = rng.integers(0, n + 1, (n, f))
+    src[0], src[1] = 1, 0
+    sel = rng.integers(0, f, (b, n))
+    root = np.append(rng.random(n) < 0.1, True)
+    root[:2] = False
+    p = 8
+    res = np.where(root & (rng.random(n + 1) < 0.5),
+                   rng.integers(0, 2 * p, n + 1), 2 * p)
+    res[n] = 2 * p
+    pin_src = rng.integers(0, 40, n + 1)
+    read = rng.permutation(n)
+    return src, sel, root, res, pin_src, read
+
+
+def _chain_tables(src, sel, root, res, pin_src, read, max_depth):
+    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+    out = fabric_chain.chain_tables(i32(sel), i32(src), i32(root), i32(res),
+                                    i32(pin_src), i32(read), max_depth)
+    return {k: np.asarray(v) for k, v in out.items()}
 
 
 @pytest.mark.parametrize("n,f", [(64, 2), (700, 6), (1500, 9)])
-def test_fabric_sweep(n, f):
-    rng = np.random.default_rng(n)
-    vals = jnp.asarray(rng.integers(0, 1000, n + 1).astype(np.int32))
-    src = jnp.asarray(rng.integers(0, n + 1, (n, f)).astype(np.int32))
-    sel = jnp.asarray(rng.integers(0, f, n).astype(np.int32))
-    np.testing.assert_array_equal(
-        np.asarray(ops.fabric_sweep(vals, src, sel)),
-        np.asarray(ref.fabric_sweep_ref(vals, src, sel)))
+def test_chain_tables_match_host_walk(n, f):
+    """Each read-out node's hop count and root equal a host walk along
+    the selected inputs: the hops to the first root, capped at
+    ``max_depth + 1`` (also where the walk cycles and never reaches a
+    root); and, within the cap, the root's PE result index and pinned
+    state index."""
+    b, max_depth = 3, f + 1
+    src, sel, root, res, pin_src, read = _chain_case(n, f, b, n)
+    got = _chain_tables(src, sel, root, res, pin_src, read, max_depth)
+    cap = max_depth + 1
+    for lane in range(b):
+        for k, j in enumerate(read):
+            node, hops = int(j), 0
+            while not root[node] and hops <= cap:
+                node, hops = int(src[node, sel[lane, node]]), hops + 1
+            assert got["hop"][lane, k] == min(hops, cap), (lane, j)
+            if hops <= cap:
+                assert got["res"][lane, k] == res[node], (lane, j)
+                assert got["cidx"][lane, k] == pin_src[node], (lane, j)
+    hop = got["hop"]
+    assert (hop == cap).any() and (hop == 0).any()
+    assert ((hop > 0) & (hop < cap)).any()
+    assert (hop[:, np.isin(read, (0, 1))] == cap).all()
 
 
 @pytest.mark.parametrize("b", [1, 5, 9])
-def test_fabric_sweep_batch(b):
-    rng = np.random.default_rng(b)
-    n, f = 300, 4
-    vals = jnp.asarray(rng.integers(0, 99, (b, n + 1)).astype(np.int32))
-    src = jnp.asarray(rng.integers(0, n + 1, (n, f)).astype(np.int32))
-    sel = jnp.asarray(rng.integers(0, f, (b, n)).astype(np.int32))
-    np.testing.assert_array_equal(
-        np.asarray(ops.fabric_sweep_batch(vals, src, sel)),
-        np.asarray(ref.fabric_sweep_batch_ref(vals, src, sel)))
-
-
-@given(st.integers(1, 10), st.integers(0, 2**31 - 1))
-@settings(max_examples=6, deadline=None)
-def test_fabric_fused_batch_vs_oracle(b, seed):
-    """Fused fixpoint kernel (gather-form PE placement, per-lane depth
-    masking) vs the scatter-form pure-jnp oracle on random tables."""
-    rng = np.random.default_rng(seed)
-    n, f, n_pe, max_depth = 150, 3, 6, 7
-    p = n_pe
-    vals0 = rng.integers(0, 1000, (b, n)).astype(np.int32)
-    sel = rng.integers(0, f, (b, n)).astype(np.int32)
-    pin_mask = (rng.random(n) < 0.2).astype(np.int32)
-    pin_vals = np.where(pin_mask[None, :] > 0, vals0, 0).astype(np.int32)
-    depths = rng.integers(0, max_depth + 1, b).astype(np.int32)
-    op = rng.integers(0, 14, (b, p)).astype(np.int32)
-    const = rng.integers(0, 1000, (b, p)).astype(np.int32)
-    imm_mask = (rng.random((b, p, 4)) < 0.25).astype(np.int32)
-    imm_val = rng.integers(0, 1000, (b, p, 4)).astype(np.int32)
-    src = rng.integers(0, n + 1, (n, f)).astype(np.int32)
-    keep = (rng.random(n) < 0.15).astype(np.int32)
-    pe_in = rng.integers(0, n + 1, (p, 4)).astype(np.int32)
-    # distinct PE output nodes, kept un-pinned so both forms agree on
-    # evaluation order (PE eval runs after pinning)
-    out_nodes = rng.choice(n, size=2 * p, replace=False).astype(np.int32)
-    pin_mask[out_nodes] = 0
-    pe_out = out_nodes.reshape(p, 2)
-    pe_res_idx = np.full(n, 2 * p, np.int32)
-    for k_ in range(p):
-        pe_res_idx[pe_out[k_, 0]] = 2 * k_
-        pe_res_idx[pe_out[k_, 1]] = 2 * k_ + 1
-    args = [jnp.asarray(x) for x in
-            (vals0, sel, pin_vals, depths, op, const, imm_mask, imm_val,
-             src, keep, pin_mask)]
-    np.testing.assert_array_equal(
-        np.asarray(ops.fabric_fused_batch(
-            *args, jnp.asarray(pe_in), jnp.asarray(pe_res_idx),
-            max_depth=max_depth)),
-        np.asarray(ref.fabric_fused_batch_ref(
-            *args, jnp.asarray(pe_in), jnp.asarray(pe_out),
-            max_depth=max_depth)))
+def test_chain_tables_lanes_independent(b):
+    """A lane's tables depend only on its own selects: a batch of ``b``
+    lanes equals each lane built alone."""
+    src, sel, root, res, pin_src, read = _chain_case(300, 4, b, b)
+    both = _chain_tables(src, sel, root, res, pin_src, read, 9)
+    for lane in range(b):
+        alone = _chain_tables(src, sel[lane:lane + 1], root, res, pin_src,
+                              read, 9)
+        for k in both:
+            np.testing.assert_array_equal(both[k][lane], alone[k][0],
+                                          err_msg=f"{k} lane {lane}")
 
 
 @given(st.integers(1, 400), st.integers(1, 9), st.integers(0, 2**31 - 1))

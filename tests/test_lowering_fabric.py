@@ -79,31 +79,24 @@ def test_conflicting_route_rejected(small_ic, fabric):
         fabric.route_to_config(edges + [(other_src, sb_out)])
 
 
-def test_pallas_fabric_sweep_matches_xla(small_ic):
-    """use_pallas=True swaps the sweep for the Pallas kernel (interpret)."""
-    fab_ref = compile_interconnect(small_ic, use_pallas=False)
-    fab_pal = compile_interconnect(small_ic, use_pallas=True)
-    edges = manual_east_route(small_ic)
-    config = jnp.asarray(fab_ref.route_to_config(edges))
-    io_idx = {c: i for i, c in enumerate(fab_ref.io_coords)}
-    T = 6
-    ext = np.zeros((T, fab_ref.num_io), np.int32)
-    ext[:, io_idx[(0, 1)]] = np.arange(7, 7 + T)
-    a = np.asarray(fab_ref.run(config, jnp.asarray(ext), depth=10))
-    b = np.asarray(fab_pal.run(config, jnp.asarray(ext), depth=10))
-    assert np.array_equal(a, b)
-
-
-def test_pallas_fabric_engine_refused_on_tpu(small_ic, monkeypatch):
-    """Mosaic refuses the fabric kernels' gathers: on a TPU backend the
-    Pallas engine fails at construction with an error that says why and
-    names the way out, while the XLA engine builds as usual."""
-    import jax
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(NotImplementedError,
-                       match=r"Mosaic(.|\n)*use_pallas=False(.|\n)*"
-                             r"Emulation engine choice"):
-        compile_interconnect(small_ic, use_pallas=True)
-    assert not compile_interconnect(small_ic, use_pallas=False).use_pallas
-
+def test_run_batch_lane_equals_run_on_manual_route(small_ic, fabric):
+    """Three registered routes (two rows, two tracks) through one
+    run_batch: each lane equals its own run, and the routed output
+    carries its input three cycles late (one register per hop)."""
+    routes = [((0, 1), manual_east_route(small_ic, y=1)),
+              ((0, 2), manual_east_route(small_ic, y=2)),
+              ((0, 1), manual_east_route(small_ic, y=1, track=1))]
+    io_idx = {c: i for i, c in enumerate(fabric.io_coords)}
+    T = 8
+    configs = np.stack([fabric.route_to_config(e) for _, e in routes])
+    ext = np.zeros((len(routes), T, fabric.num_io), np.int32)
+    for b, (src, _) in enumerate(routes):
+        ext[b, :, io_idx[src]] = np.arange(10 * b + 7, 10 * b + 7 + T)
+    out = np.asarray(fabric.run_batch(jnp.asarray(configs),
+                                      jnp.asarray(ext), depth=12))
+    for b, (src, _) in enumerate(routes):
+        single = np.asarray(fabric.run(jnp.asarray(configs[b]),
+                                       jnp.asarray(ext[b]), depth=12))
+        np.testing.assert_array_equal(out[b], single)
+        want = [0, 0, 0] + list(range(10 * b + 7, 10 * b + 7 + T - 3))
+        assert list(out[b, :, io_idx[(3, src[1])]]) == want

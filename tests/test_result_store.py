@@ -24,7 +24,6 @@ FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
 def _executor(store, **kw):
     kw.setdefault("apps", {"pw": lambda: app_pointwise(1)})
     kw.setdefault("emulate_cycles", 6)
-    kw.setdefault("use_pallas", False)
     kw.setdefault("max_workers", 1)
     return SweepExecutor(store=store, **kw)
 
@@ -403,8 +402,8 @@ def test_same_digest_coalesces_through_emulation_tail(tmp_path):
     ex = _executor(ResultStore(str(tmp_path / "s")))
     real = ex._emulate_batch
 
-    def parked(fab, routed, device=None, io_chunk=None):
-        out = real(fab, routed, device=device, io_chunk=io_chunk)
+    def parked(fab, routed, device=None):
+        out = real(fab, routed, device=device)
         assert gate.wait(timeout=60)
         return out
 
@@ -468,7 +467,7 @@ def test_resolved_digest_pins_knobs_and_shares_hardware(tmp_path):
 def test_service_single_and_batch_queries(tmp_path):
     svc = canal.serve(store=str(tmp_path / "s"),
                       apps={"pw": lambda: app_pointwise(1)},
-                      emulate_cycles=0, use_pallas=False, max_workers=1)
+                      emulate_cycles=0, max_workers=1)
     spec = InterconnectSpec(**SMOKE)
     rec = svc.query(spec)                         # single in -> dict out
     assert rec["apps"]["pw"]["success"]
@@ -491,12 +490,12 @@ def test_service_warm_query_hits_only(tmp_path):
     specs = [InterconnectSpec(**SMOKE),
              InterconnectSpec(**dict(SMOKE, num_tracks=3))]
     svc1 = canal.serve(store=root, apps=apps, emulate_cycles=0,
-                       use_pallas=False, max_workers=1)
+                       max_workers=1)
     svc1.query(specs)
     svc1.close()
 
     svc2 = canal.serve(store=root, apps=apps, emulate_cycles=0,
-                       use_pallas=False, max_workers=1)
+                       max_workers=1)
     out = svc2.query(specs)                       # fresh process-alike
     st = svc2.stats()
     assert st["hits"] == 2 and st["misses"] == 0
@@ -510,7 +509,7 @@ def test_service_warm_query_hits_only(tmp_path):
 def test_service_duplicate_specs_in_one_query(tmp_path):
     svc = canal.serve(store=str(tmp_path / "s"),
                       apps={"pw": lambda: app_pointwise(1)},
-                      emulate_cycles=0, use_pallas=False, max_workers=1)
+                      emulate_cycles=0, max_workers=1)
     spec = InterconnectSpec(**SMOKE)
     out = svc.query([spec, dict(SMOKE), spec])    # legacy kwargs too
     assert len(out) == 3
@@ -531,7 +530,7 @@ def test_service_concurrent_queries_coalesce(tmp_path):
         return app_pointwise(1)
 
     svc = canal.serve(store=str(tmp_path / "s"), apps={"pw": slow_app},
-                      emulate_cycles=0, use_pallas=False, max_workers=1)
+                      emulate_cycles=0, max_workers=1)
     spec = InterconnectSpec(**SMOKE)
     f1 = svc.submit(spec)
     assert entered.wait(timeout=30)
@@ -559,12 +558,12 @@ def test_service_probe_failure_resolves_claimed_futures(tmp_path):
     spec = InterconnectSpec(**SMOKE)
     specs = [spec, spec.replace(num_tracks=3)]
     warm = canal.serve(store=root, apps=apps, emulate_cycles=0,
-                       use_pallas=False, max_workers=1)
+                       max_workers=1)
     warm.query(spec)                              # a record to probe
     warm.close()
 
     svc = canal.serve(store=root, apps=apps, emulate_cycles=0,
-                      use_pallas=False, max_workers=1)
+                      max_workers=1)
     svc.executor.record_usable = \
         lambda rec: (_ for _ in ()).throw(TypeError("malformed record"))
     with pytest.raises(TypeError, match="malformed record"):
@@ -584,7 +583,7 @@ def test_service_cold_point_probes_store_exactly_once(tmp_path):
     one store miss per cold point, one store hit per warm one."""
     svc = canal.serve(store=str(tmp_path / "s"),
                       apps={"pw": lambda: app_pointwise(1)},
-                      emulate_cycles=0, use_pallas=False, max_workers=1)
+                      emulate_cycles=0, max_workers=1)
     specs = [InterconnectSpec(**SMOKE),
              InterconnectSpec(**dict(SMOKE, num_tracks=3))]
     svc.query(specs)
@@ -702,7 +701,7 @@ def test_store_concurrent_alternating_app_sets(tmp_path):
 def test_canal_serve_is_the_front_door(tmp_path):
     from repro.serve.dse_service import DSEService
     svc = canal.serve(store=str(tmp_path / "s"), apps={},
-                      emulate_cycles=0, use_pallas=False)
+                      emulate_cycles=0)
     assert isinstance(svc, DSEService)
     assert svc.store.root == str(tmp_path / "s")
     svc.close()

@@ -313,8 +313,7 @@ def test_greedy_search_matches_grid_best_with_fewer_evals(tmp_path):
     apps = {"pw": lambda: app_pointwise(1)}
     tracks = (2, 3, 4, 5, 6)
     grid_ex = SweepExecutor(apps=apps, store=ResultStore(
-        str(tmp_path / "grid")), emulate_cycles=0, use_pallas=False,
-        max_workers=1)
+        str(tmp_path / "grid")), emulate_cycles=0, max_workers=1)
     grid = sweep_num_tracks(tracks, width=4, height=4, executor=grid_ex)
     best_grid = _grid_best(grid)
     assert grid_ex.pnr_computations == len(tracks)
@@ -324,7 +323,7 @@ def test_greedy_search_matches_grid_best_with_fewer_evals(tmp_path):
                  objective="area",
                  constraints={"min_routability": 1.0},
                  budget=4, batch_size=2, seed=0, store=store,
-                 apps=apps, use_pallas=False, max_workers=1)
+                 apps=apps, max_workers=1)
     best = res.best("area", {"min_routability": 1.0})
     assert best is not None
     assert best.digest == best_grid["spec_digest"]     # same optimum
@@ -336,7 +335,7 @@ def test_greedy_search_matches_grid_best_with_fewer_evals(tmp_path):
                   objective="area",
                   constraints={"min_routability": 1.0},
                   budget=4, batch_size=2, seed=0, store=store,
-                  apps=apps, use_pallas=False, max_workers=1)
+                  apps=apps, max_workers=1)
     assert res2.stats["executor"]["pnr_computations"] == 0  # zero PnR
     assert res2.stats["executor"]["store_hits"] == len(res2.evaluated)
     assert res2.best("area", {"min_routability": 1.0}).digest == \
@@ -353,7 +352,7 @@ def test_evolutionary_search_finds_grid_best(tmp_path):
                  constraints={"min_routability": 1.0},
                  budget=3, batch_size=3, seed=0,
                  store=str(tmp_path / "s"), apps=apps,
-                 use_pallas=False, max_workers=1)
+                 max_workers=1)
     best = res.best("area", {"min_routability": 1.0})
     assert best is not None and best.spec.num_tracks == 2
 
@@ -363,7 +362,7 @@ def test_recommend_serving_verb(tmp_path):
     and its second recommendation is pure store hits."""
     svc = canal.serve(store=str(tmp_path / "s"),
                       apps={"pw": lambda: app_pointwise(1)},
-                      emulate_cycles=0, use_pallas=False, max_workers=1)
+                      emulate_cycles=0, max_workers=1)
     out = svc.recommend(BASE, {"num_tracks": [2, 3]},
                         objective="area",
                         constraints={"min_routability": 1.0},

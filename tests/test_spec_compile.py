@@ -324,8 +324,7 @@ def test_run_point_spec_equals_kwargs():
     kw = dict(width=6, height=6, num_tracks=4, io_ring=True,
               reg_density=1.0)
     ex = SweepExecutor(apps={"pw": lambda: app_pointwise(1)}, sa_steps=20,
-                       sa_batch=8, emulate_cycles=6, use_pallas=False,
-                       max_workers=1)
+                       sa_batch=8, emulate_cycles=6, max_workers=1)
     rec_kw = ex.run_point(kw, {"tag": 1})
     rec_spec = ex.run_point(InterconnectSpec(**kw), {"tag": 1})
     assert len(ex._ic_cache) == 1                    # one shared entry

@@ -13,9 +13,8 @@ coarse graph. The topology is described inside a module fixture, never
 at import: only one process at a time may load the TPU library, and it
 keeps it until it exits, so every test of this kind stays in this file.
 
-The Pallas fabric kernels (``kernels/fabric_step.py``) are not here:
-Mosaic refuses their gathers, and the fabric model emulates with the XLA
-engine tested below instead.
+The fabric's emulation engine is XLA, not Pallas: the chain engine and
+its sweep oracle are compiled below at Amber widths.
 """
 import functools
 import os
@@ -119,10 +118,9 @@ def test_fused_xla_emulation_step_compiles(one_chip):
 
 
 def test_chain_emulation_step_compiles(one_chip):
-    """The served emulation step (``use_pallas=False``): the chain
-    engine's per-batch tables and one cycle's fixpoint for the
-    benchmark's batch on Amber's array (B = 5, depth 170) fit one
-    chip."""
+    """The served emulation step: the chain engine's per-batch tables
+    and one cycle's fixpoint for the benchmark's batch on Amber's array
+    (B = 5, depth 170) fit one chip."""
     b, depth, f = 5, 170, FULL_FANIN
     n, p = AMBER["n"], AMBER["p"]
     k = 4 * p + AMBER["r"] + AMBER["m"] + AMBER["io"]
