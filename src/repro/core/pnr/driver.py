@@ -10,6 +10,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.core import trace
 from repro.core.graph import Interconnect, Node
+from repro.kernels.minplus import wavefront_programs
 from .app import AppGraph
 from .packing import PackedGraph, pack
 from .global_place import (assign_ios, global_place, legalize,
@@ -118,7 +119,8 @@ def place_and_route(ic: Interconnect, app: AppGraph,
                                     res=resources, seed=seed,
                                     strategy=route_strategy,
                                     auto_min_tiles=auto_min_tiles)
-                s.set(engine=routing.strategy, rounds=routing.iterations)
+                s.set(engine=routing.strategy, rounds=routing.iterations,
+                      programs=wavefront_programs())
         except RoutingError as e:
             last_err = str(e)
             continue

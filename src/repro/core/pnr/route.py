@@ -297,6 +297,7 @@ class CoarseGraph:
             missing = tiles
         rows: Dict[int, np.ndarray] = {}
         if missing:
+            import jax
             from repro.kernels import ops as kops
 
             w = self.lower_bound_weights(
@@ -312,9 +313,12 @@ class CoarseGraph:
                 bucket *= 2
             d0 = np.full((bucket, self.n_tiles), COARSE_INF, np.float32)
             d0[np.arange(len(missing)), missing] = 0.0
-            relaxed = kops.minplus_wavefront(d0, w.T.astype(np.float32))
-            with trace.span("device.wait"):
-                out = np.asarray(relaxed, np.float64)
+            relaxed, blocks = kops.minplus_wavefront_blocks(
+                d0, w.T.astype(np.float32))
+            with trace.span("device.wait") as wait:
+                out, blocks = jax.device_get((relaxed, blocks))
+                wait.set(blocks=int(blocks))
+            out = out.astype(np.float64)
             for row, t in zip(out, missing):
                 rows[t] = row
                 if zero_hist:

@@ -16,11 +16,13 @@ PathFinder outer loop (``repro.core.pnr.route``, ``strategy="minplus"``)
 uses these cost fields as its batched A* lower bounds.
 
 ``minplus_wavefront`` is the router-facing entry point: it relaxes in
-device-side blocks and stops as soon as the field stops changing, so the
+blocks and stops as soon as a block leaves the field unchanged, so the
 iteration count adapts to the graph diameter instead of paying the full
-Bellman-Ford ``N - 1`` bound. ``engine="auto"`` runs the Pallas kernel
-compiled on TPU and the jitted dense reference elsewhere (interpret mode
-would only be slower).
+Bellman-Ford ``N - 1`` bound. The block loop and its convergence test
+run on the device in one compiled program per seed batch and tile count
+(``_wavefront``): a call is one dispatch, with no host sync per block.
+``engine="auto"`` runs the Pallas kernel compiled on TPU and the dense
+reference elsewhere (interpret mode would only be slower).
 
 The kernel compiles for TPU v5e (``tests/test_tpu_compile.py``: 1024
 tiles, batches up to 1024). Its scoped VMEM grows with the batch rows of
@@ -35,13 +37,11 @@ Validated in interpret mode against ``ref.minplus_ref`` /
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.core import trace
 
 BLOCK = 128
 BATCH_BLOCK = 256      # batch rows per grid step (scoped-VMEM bound)
@@ -128,19 +128,59 @@ def _ref_block(d: jnp.ndarray, w: jnp.ndarray, iters: int) -> jnp.ndarray:
     return jax.lax.fori_loop(0, iters, body, d)
 
 
-def minplus_wavefront(d0: jnp.ndarray, w: jnp.ndarray,
-                      block_iters: int = 8,
-                      engine: str = "auto",
-                      interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Relax ``d0`` to the true shortest-path fixpoint, adaptively.
+@functools.partial(jax.jit, static_argnames=("block_iters", "use_kernel",
+                                             "interpret"))
+@jax.named_scope("canal.minplus")
+def _wavefront(d: jnp.ndarray, w: jnp.ndarray, block_iters: int,
+               use_kernel: bool, interpret: bool
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The whole adaptive wavefront as one program: blocks of
+    ``block_iters`` relaxations until a block leaves the field unchanged
+    or the ``N - 1`` cap is reached. Returns the field and the number of
+    blocks run."""
+    n = w.shape[0]
+    max_blocks = max(1, -(-max(n - 1, 1) // block_iters))
 
-    Runs ``block_iters`` relaxations per device dispatch and stops when a
-    block leaves the field unchanged (a min-plus fixpoint is stable, so
-    one unchanged block proves convergence); a cap of ``N - 1`` total
+    def more(state):
+        _, blocks, done = state
+        return (blocks < max_blocks) & ~done
+
+    def block(state):
+        d, blocks, _ = state
+        if use_kernel:
+            nd = minplus_fixpoint(d, w, block_iters, interpret=interpret)
+        else:
+            nd = _ref_block(d, w, block_iters)
+        return nd, blocks + 1, jnp.all(nd == d)
+
+    d, blocks, _ = jax.lax.while_loop(
+        more, block, (d, jnp.int32(0), jnp.bool_(False)))
+    return d, blocks
+
+
+def wavefront_programs() -> int:
+    """Compiled wavefront programs this process holds: one per seed
+    bucket and tile count (and engine) seen."""
+    return _wavefront._cache_size()
+
+
+def minplus_wavefront_blocks(d0: jnp.ndarray, w: jnp.ndarray,
+                             block_iters: int = 8,
+                             engine: str = "auto",
+                             interpret: Optional[bool] = None
+                             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Relax ``d0`` to the true shortest-path fixpoint, adaptively;
+    returns the field and the number of blocks run, both on the device.
+
+    Runs ``block_iters`` relaxations per block and stops when a block
+    leaves the field unchanged (a min-plus fixpoint is stable, so one
+    unchanged block proves convergence); a cap of ``N - 1`` total
     relaxations preserves the Bellman-Ford bound on adversarial graphs.
+    The loop and its test run on the device: one dispatch per call, and
+    one compiled program per input shape.
 
-    engine: ``"pallas"`` forces the blocked kernel, ``"ref"`` the jitted
-    dense reference, ``"auto"`` picks the kernel only where it compiles
+    engine: ``"pallas"`` forces the blocked kernel, ``"ref"`` the dense
+    reference, ``"auto"`` picks the kernel only where it compiles
     (TPU) — on interpret-mode hosts the reference is the faster exact
     implementation of the same contract.
     """
@@ -149,18 +189,17 @@ def minplus_wavefront(d0: jnp.ndarray, w: jnp.ndarray,
     if engine not in ("auto", "pallas", "ref"):
         raise ValueError(f"unknown minplus engine {engine!r}")
     use_kernel = engine == "pallas" or (engine == "auto" and not interpret)
-    d = jnp.asarray(d0, jnp.float32)
-    w = jnp.asarray(w, jnp.float32)
-    n = w.shape[0]
-    max_blocks = max(1, -(-max(n - 1, 1) // block_iters))
-    for _ in range(max_blocks):
-        if use_kernel:
-            nd = minplus_fixpoint(d, w, block_iters, interpret=interpret)
-        else:
-            nd = _ref_block(d, w, block_iters)
-        with trace.span("device.wait"):
-            done = bool(jnp.array_equal(nd, d))
-        if done:
-            return nd
-        d = nd
-    return d
+    # the reference has no interpret mode: one program key either way
+    return _wavefront(jnp.asarray(d0, jnp.float32),
+                      jnp.asarray(w, jnp.float32), block_iters=block_iters,
+                      use_kernel=use_kernel,
+                      interpret=bool(interpret) and use_kernel)
+
+
+def minplus_wavefront(d0: jnp.ndarray, w: jnp.ndarray,
+                      block_iters: int = 8,
+                      engine: str = "auto",
+                      interpret: Optional[bool] = None) -> jnp.ndarray:
+    """The converged field of :func:`minplus_wavefront_blocks`."""
+    return minplus_wavefront_blocks(d0, w, block_iters, engine,
+                                    interpret)[0]

@@ -11,6 +11,8 @@ Mosaic compiles ``minplus_*``, ``hpwl`` and ``net_bboxes`` for TPU v5e
 """
 from __future__ import annotations
 
+from typing import Tuple
+
 import jax.numpy as jnp
 
 from . import flash_attention as _flash
@@ -45,6 +47,13 @@ def minplus_wavefront(d0: jnp.ndarray, w: jnp.ndarray,
     elsewhere."""
     return _minplus.minplus_wavefront(d0, w, engine=engine,
                                       interpret=_interpret())
+
+
+def minplus_wavefront_blocks(d0: jnp.ndarray, w: jnp.ndarray
+                             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`minplus_wavefront`'s field and the number of relaxation
+    blocks it took, both on the device."""
+    return _minplus.minplus_wavefront_blocks(d0, w, interpret=_interpret())
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

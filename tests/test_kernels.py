@@ -230,6 +230,88 @@ def test_minplus_wavefront_converges_to_bellman_ford(engine):
     np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
+def _host_wavefront(d0, w, block_iters=8):
+    """Host oracle of the block loop: relax in blocks of ``block_iters``
+    on the host, stop after the first block that leaves the field
+    unchanged or at ``ceil((N-1)/block_iters)`` blocks. Returns the
+    field and the blocks run."""
+    cap = max(1, -(-max(w.shape[0] - 1, 1) // block_iters))
+    d = d0
+    for blocks in range(1, cap + 1):
+        nd = d
+        for _ in range(block_iters):
+            nd = np.minimum(nd, np.min(nd[:, :, None] + w[None], axis=1))
+        if np.array_equal(nd, d):
+            return nd, blocks
+        d = nd
+    return d, cap
+
+
+def _sparse_graph():
+    n, b = 96, 4
+    rng = np.random.default_rng(7)
+    w = np.where(rng.random((n, n)) < 0.06, rng.random((n, n)) * 3 + 0.1,
+                 3e37).astype(np.float32)
+    np.fill_diagonal(w, 0.0)
+    d0 = np.full((b, n), 3e37, np.float32)
+    d0[np.arange(3), [0, 5, 11]] = 0.0          # row 3: an all-INF pad lane
+    return d0, w
+
+
+def _line_graph():
+    """A path 0 -> 1 -> ... -> 24: the far end is reached at relaxation
+    N - 1 = 24, so every block changes the field and the cap ends it."""
+    n = 25
+    w = np.full((n, n), 3e37, np.float32)
+    np.fill_diagonal(w, 0.0)
+    w[np.arange(n - 1), np.arange(1, n)] = 1.0
+    d0 = np.full((2, n), 3e37, np.float32)
+    d0[0, 0] = 0.0
+    return d0, w
+
+
+@pytest.mark.parametrize("graph", [_sparse_graph, _line_graph],
+                         ids=["sparse", "line"])
+@pytest.mark.parametrize("engine", ["pallas", "ref"])
+def test_minplus_wavefront_equals_host_block_loop(engine, graph):
+    """The device block loop returns the host oracle's field bit for bit
+    and runs as many blocks."""
+    from repro.kernels.minplus import minplus_wavefront_blocks
+
+    d0, w = graph()
+    field, blocks = minplus_wavefront_blocks(
+        jnp.asarray(d0), jnp.asarray(w), engine=engine, interpret=True)
+    want, want_blocks = _host_wavefront(d0, w)
+    np.testing.assert_array_equal(np.asarray(field), want)
+    assert int(blocks) == want_blocks
+    if graph is _line_graph:
+        assert want_blocks == 3
+        np.testing.assert_array_equal(want[0], np.arange(25, dtype=np.float32))
+
+
+@pytest.mark.parametrize("engine", ["pallas", "ref"])
+def test_minplus_wavefront_compiles_once_per_shape(engine):
+    """A second and third call at the same shape reuse the first call's
+    program: no new program, and no backend compile."""
+    from repro.core import trace
+    from repro.kernels.minplus import minplus_wavefront, wavefront_programs
+
+    d0, w = _sparse_graph()
+    minplus_wavefront(jnp.asarray(d0), jnp.asarray(w), engine=engine,
+                      interpret=True).block_until_ready()
+    programs = wavefront_programs()
+    rng = np.random.default_rng(1)
+    with trace.recording() as rec:
+        for _ in range(2):
+            w2 = np.where(w < 3e37, w * rng.random(w.shape).astype(
+                np.float32), w)
+            minplus_wavefront(jnp.asarray(d0), jnp.asarray(w2),
+                              engine=engine,
+                              interpret=True).block_until_ready()
+    assert wavefront_programs() == programs
+    assert rec.unattributed["jit_n"] == 0
+
+
 @pytest.mark.parametrize("sq,skv,hq,hkv,dtype", [
     (128, 128, 4, 4, jnp.float32),
     (200, 200, 4, 2, jnp.float32),

@@ -87,6 +87,18 @@ def test_minplus_fixpoint_compiles(one_chip):
     assert _has_kernel(compiled)
 
 
+@pytest.mark.parametrize("batch", [1, 1024])
+def test_minplus_wavefront_compiles(one_chip, batch):
+    """The router's whole cost-field program, the block loop and its
+    convergence test included, at the smallest and largest bucket."""
+    d = _shape(one_chip, (batch, FULL_TILES), jnp.float32)
+    w = _shape(one_chip, (FULL_TILES, FULL_TILES), jnp.float32)
+    compiled = _compile(functools.partial(minplus._wavefront, block_iters=8,
+                                          use_kernel=True, interpret=False),
+                        d, w)
+    assert _has_kernel(compiled)
+
+
 @pytest.mark.parametrize("kernel", [hpwl.hpwl, hpwl.net_bboxes],
                          ids=["hpwl", "net_bboxes"])
 def test_net_box_kernels_compile(one_chip, kernel):

@@ -167,6 +167,11 @@ def test_cold_served_point_records_every_span(tmp_path):
     route = next(s for s in rec.spans if s.name == "route.app")
     assert route.attrs["engine"] == "minplus"
     assert route.attrs["rounds"] >= 1
+    assert route.attrs["programs"] >= 1
+    route_waits = [s for s in rec.spans if s.name == "device.wait"
+                   and by_id[s.parent].name == "route.app"]
+    assert route_waits
+    assert all(s.attrs["blocks"] >= 1 for s in route_waits)
     assert by_id[route.parent].name == "pnr"
     assert by_id[route.parent].attrs == {"app": "pointwise"}
     glob = next(s for s in rec.spans if s.name == "place.global")
@@ -216,6 +221,9 @@ def test_minplus_scope_in_lowered_text():
     assert "canal.minplus" in _lowered(minplus.minplus_step, d, w,
                                        interpret=True)
     assert "canal.minplus" in _lowered(minplus._ref_block, d, w, iters=2)
+    assert "canal.minplus" in _lowered(minplus._wavefront, d, w,
+                                       block_iters=2, use_kernel=False,
+                                       interpret=False)
 
 
 def test_emulate_run_records_slots_per_sweep():
